@@ -68,7 +68,7 @@ class ContractSpec:
       HLO (GSPMD may insert its own); anything else found is a finding.
       Triggers a ``.compile()`` of the lowering.
     * ``forbid_f64`` / ``enable_x64`` — JXP002 traces the program under
-      ``jax.experimental.enable_x64()`` and flags any f64/c128 equation
+      ``jax.enable_x64(True)`` and flags any f64/c128 equation
       output: a literal or helper that silently widens under x64.
     * ``out_dtypes`` — expected dtype names of the program outputs on
       the normal (x64-off) trace, e.g. ``("bfloat16",)`` for the bf16

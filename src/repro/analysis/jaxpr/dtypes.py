@@ -4,7 +4,7 @@ The master plane is f32 and the kernel layer round-trips bf16 leaves;
 nothing on the hot path may silently widen.  Two checks:
 
 * **f64 promotion probe** — re-trace the program under
-  ``jax.experimental.enable_x64()``.  Weak-typed Python scalars stay
+  ``jax.enable_x64(True)``.  Weak-typed Python scalars stay
   narrow under x64; *strong* f64 values (``jnp.array([0.5])`` with no
   dtype, ``np.asarray`` constants, ``np.float64`` scalars) widen the
   whole downstream graph.  Any equation producing an f64/c128 output

@@ -4,12 +4,12 @@ PR 7 found the mesh plane round running at HALF speed because a jitted
 kernel fallback called from inside the round's ``fori_loop`` lowered to
 a nested XLA call boundary that blocked fusion with the surrounding
 loop body.  The fix (``ops._tracing``) inlines the expression when
-already under a trace — this pass pins that property: no ``pjit`` /
+already under a trace — this pass pins that property: no ``jit`` /
 ``closed_call`` / ``custom-call`` equation may appear inside a
 ``scan``/``while`` body.
 
 Escape hatches, because jax.numpy itself jits tiny helpers
-(``take_along_axis`` traces as a nested pjit on jax 0.4.x):
+(``take_along_axis`` can trace as a nested jit):
 
 * ``DEFAULT_FUSION_ALLOW`` + the contract's ``fusion_allow`` — inner
   jits allowed *by name*;
@@ -26,9 +26,8 @@ from repro.analysis.jaxpr.passes import (AuditFinding, audit_pass,
 #: Loop primitives whose bodies must stay call-free.
 LOOP_PRIMS = ("scan", "while")
 
-#: Call-boundary primitives (jax 0.4.x spells nested jit `pjit`).
-CALL_PRIMS = ("pjit", "closed_call", "core_call", "xla_call",
-              "custom_call")
+#: Call-boundary primitives (a nested jit traces as `jit`).
+CALL_PRIMS = ("jit", "closed_call", "core_call", "custom_call")
 
 #: jax.numpy-internal helper jits that XLA inlines anyway.
 DEFAULT_FUSION_ALLOW = ("take_along_axis", "_where", "_one_hot",

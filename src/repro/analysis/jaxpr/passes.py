@@ -5,7 +5,7 @@ List[AuditFinding]`` registered in :data:`PASSES`.  :class:`ProgramTrace`
 owns the (lazily computed, cached) artifacts every pass reads:
 
 * ``jaxpr()`` — ``jax.make_jaxpr`` of the program on its example args;
-* ``jaxpr_x64()`` — the same trace under ``jax.experimental.enable_x64``
+* ``jaxpr_x64()`` — the same trace under ``jax.enable_x64(True)``
   (the f64-promotion probe: weak Python scalars stay narrow, strong
   literals widen — exactly what runs if a user flips the flag);
 * ``lowered_text()`` — StableHLO of the jitted lowering (with the
@@ -25,11 +25,7 @@ from typing import Callable, Dict, Iterator, List, Tuple
 
 import jax
 
-try:    # jax >= 0.4.36 moved the jaxpr types to jax.extend.core
-    from jax.extend import core as _core
-    _ = (_core.Jaxpr, _core.ClosedJaxpr)
-except (ImportError, AttributeError):           # pragma: no cover
-    from jax import core as _core               # type: ignore[no-redef]
+from jax.extend import core as _core
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,8 +151,7 @@ class ProgramTrace:
 
     def jaxpr_x64(self):
         def trace():
-            from jax.experimental import enable_x64
-            with enable_x64():
+            with jax.enable_x64(True):
                 return jax.make_jaxpr(self.program.fn)(*self.program.args)
         return self._memo("jaxpr_x64", trace)
 

@@ -822,7 +822,7 @@ def _check_loop_callbacks(mod: _Module,
 
 
 # dtype's positional slot; `full` is deliberately absent — a Python-
-# scalar fill keeps the result WEAK-typed (verified on jax 0.4.37), so
+# scalar fill keeps the result WEAK-typed (verified on jax 0.9.0), so
 # it cannot widen anything
 _RPA010_DTYPE_POS = {"array": 1, "asarray": 1}
 _RPA010_FACTORIES = {"linspace", "logspace", "geomspace"}

@@ -23,6 +23,7 @@ import sys
 from repro.experiments import (TraceSink, available_experiments,
                                build_context, from_json, get_experiment,
                                run as run_one, sweep, to_json)
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _load_spec(name: str):
@@ -168,4 +169,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the command line, not main(): tests call main() and keep JAX's
+    # default cache settings
+    enable_compile_cache()
     sys.exit(main())

@@ -77,7 +77,7 @@ def _compiler_params(plan: TilePlan, interpret: bool, semantics):
     dims of these kernels are embarrassingly parallel unless marked)."""
     if interpret or plan.backend != "tpu":    # repro: noqa(RPA004) static flag + static plan metadata
         return None
-    return pltpu.TPUCompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _kernel(x_ref, g_ref, a_ref, eta_ref, mu_ref, o_ref):
@@ -120,8 +120,15 @@ def _accum_kernel(x_ref, g_ref, anc_ref, acc_ref, coef_ref, act_ref,
                   eta_ref, mu_ref, ox_ref, oacc_ref):
     eta = eta_ref[0, 0]
     mu = mu_ref[0, 0]
-    a_k = coef_ref[0, :][:, None, None]         # (gblk, 1, 1)
-    act = act_ref[0, :][:, None, None]
+    if x_ref.shape[0] == 1:
+        # one DPU per grid step: its scalars from the whole (1, G) SMEM
+        # arrays, indexed by the DPU grid coordinate
+        i = pl.program_id(0)
+        a_k = coef_ref[0, i]
+        act = act_ref[0, i]
+    else:
+        a_k = coef_ref[0, :][:, None, None]     # (gblk, 1, 1)
+        act = act_ref[0, :][:, None, None]
     x = x_ref[...].astype(jnp.float32)          # (gblk, rows, lanes)
     g = g_ref[...].astype(jnp.float32)
     anc = anc_ref[...].astype(jnp.float32)      # (rows, lanes) or (gblk, ...)
@@ -160,7 +167,9 @@ def fedprox_accum_2d(x, g, anchor, acc, coef, active, eta, mu, *,
     else:
         assert anchor.shape == x.shape
         aspec = bspec
-    pspec = pl.BlockSpec((1, gblk), lambda i, j, k: (0, i))  # per-DPU scalars
+    # per-DPU scalars: the whole (1, G) array in SMEM (a (1, gblk) VMEM
+    # block would break Mosaic's (8, 128) block rule)
+    pspec = pl.BlockSpec(memory_space=pltpu.SMEM)
     sspec = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
     coef = jnp.asarray(coef, jnp.float32).reshape(1, G)
     active = jnp.asarray(active, jnp.float32).reshape(1, G)

@@ -72,7 +72,7 @@ def _kernel_acc(x_ref, d_ref, w_ref, se_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += w_ref[0, 0] * d_ref[0].astype(jnp.float32)
+    acc_ref[...] += w_ref[0, k] * d_ref[0].astype(jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
@@ -112,7 +112,7 @@ def nova_aggregate_2d(x, d_stack, weights, theta_eta, *,
     grid = (pl.cdiv(R, rows), pl.cdiv(L, lanes), n)
     xspec = pl.BlockSpec((rows, lanes), lambda i, j, k: (i, j))
     dspec = pl.BlockSpec((1, rows, lanes), lambda i, j, k: (k, i, j))
-    wspec = pl.BlockSpec((1, 1), lambda i, j, k: (0, k))
+    wspec = pl.BlockSpec(memory_space=pltpu.SMEM)    # whole (1, n), by k
     sspec = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
     return pl.pallas_call(
         _kernel_acc,
@@ -143,7 +143,7 @@ def _kernel_stacked_acc(x_ref, d_ref, w_ref, se_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += w_ref[0, 0] * d_ref[0].astype(jnp.float32)
+    acc_ref[...] += w_ref[0, k] * d_ref[0].astype(jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
@@ -182,7 +182,7 @@ def nova_aggregate_stacked_2d(x, d_stack, weights, theta_eta, *,
     # same update); only d is streamed one DPU tile at a time.
     xspec = pl.BlockSpec((n, rows, lanes), lambda i, j, k: (0, i, j))
     dspec = pl.BlockSpec((1, rows, lanes), lambda i, j, k: (k, i, j))
-    wspec = pl.BlockSpec((1, 1), lambda i, j, k: (0, k))
+    wspec = pl.BlockSpec(memory_space=pltpu.SMEM)    # whole (1, n), by k
     sspec = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
     return pl.pallas_call(
         _kernel_stacked_acc,

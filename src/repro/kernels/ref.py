@@ -34,22 +34,43 @@ def nova_aggregate_ref(x, d_stack, weights, theta_eta):
     return (x.astype(jnp.float32) - theta_eta * agg).astype(x.dtype)
 
 
+def sort_network(slabs):
+    """Sort a list of equal-shape arrays elementwise, ascending, with a
+    static odd-even transposition network: n rounds of compare-exchanges
+    of neighbours.  O(n^2) elementwise ops and no sort primitive, so the
+    same expression lowers inside a Pallas TPU kernel, where ``sort`` has
+    no lowering.  NaN sorts last, as in ``jnp.sort``, so a trimmed mean
+    still trims a NaN payload."""
+    v = list(slabs)
+    n = len(v)
+    for r in range(n):
+        for i in range(r % 2, n - 1, 2):
+            a, b = v[i], v[i + 1]
+            swap = (a > b) | (a != a)
+            v[i], v[i + 1] = jnp.where(swap, b, a), jnp.where(swap, a, b)
+    return v
+
+
 def robust_reduce_ref(d_stack, *, k: int = 0, median: bool = False):
     """Coordinate-wise robust location estimate over the DPU axis.
 
     ``median=True``: the coordinate-wise median; otherwise the k-trimmed
     mean (drop the k smallest and k largest per coordinate — requires
     2k < n).  Unweighted by design: dataset-size weights are exactly the
-    lever a byzantine client can inflate.
+    lever a byzantine client can inflate.  The kernel body calls this
+    same function, so the ``cpu`` and Pallas backends agree bit for bit.
     """
-    d = jnp.sort(d_stack.astype(jnp.float32), axis=0)
-    n = d.shape[0]
+    n = d_stack.shape[0]
+    if not median and not 0 <= 2 * k < n:
+        raise ValueError(f"trim k={k} needs 0 <= 2k < n={n}")
+    d = sort_network([d_stack[i].astype(jnp.float32) for i in range(n)])
     if median:
         mid = n // 2
         return d[mid] if n % 2 else 0.5 * (d[mid - 1] + d[mid])
-    if not 0 <= 2 * k < n:
-        raise ValueError(f"trim k={k} needs 0 <= 2k < n={n}")
-    return jnp.mean(d[k:n - k], axis=0)
+    total = d[k]
+    for x in d[k + 1:n - k]:
+        total = total + x
+    return total / (n - 2 * k)
 
 
 def robust_aggregate_ref(x, d_stack, theta_eta, *, k: int = 0,

@@ -12,8 +12,10 @@ Kernel shape: the reduce needs every DPU's value of a coordinate at once
 (a sort along the stack axis), so the DPU axis can never be a grid
 dimension the way the nova grid-accumulation streams it.  Instead the
 grid tiles the (rows, lanes) plane and each step loads the full
-``(n_dpu, rows, lanes)`` d block and sorts in-register — fine for the
-n_dpu counts a robust quorum makes sense at (tens), and the
+``(n_dpu, rows, lanes)`` d block and sorts it with the static odd-even
+transposition network of ``ref.sort_network`` (``sort`` has no Pallas
+TPU lowering; the network is O(n_dpu^2) compare-exchanges) — fine for
+the n_dpu counts a robust quorum makes sense at (tens), and the
 :class:`~repro.kernels.tiling.TilePlan` budget accounts the n-fold
 resident block (``ops.robust_aggregate_plane`` passes
 ``n_operands = n + 3``).  Like the PR-7 tiled grids, the compiled form

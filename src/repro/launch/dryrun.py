@@ -113,8 +113,6 @@ def dryrun_combo(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     amp = amplified_costs(hlo)          # trip-count-aware totals

@@ -5,13 +5,9 @@ from __future__ import annotations
 import jax
 
 
-def _auto_kw(n):
-    """axis_types=Auto on jax versions that have it (>= 0.5); {} otherwise
-    (older jax is Auto-only, so omitting the kwarg is equivalent)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def _auto(n):
+    """Every mesh axis in Auto mode (GSPMD propagates the shardings)."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,11 +15,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     multi-pod ('pod','data','model')."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_kw(len(shape)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever local devices exist (tests / examples)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"), **_auto_kw(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))

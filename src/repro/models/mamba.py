@@ -114,10 +114,12 @@ def ssd_forward(params: dict, x_in: jnp.ndarray, s: SSMConfig,
     chunk_decay = cum[:, :, -1]                                  # (B,nc,H)
 
     # intra-chunk (dual / attention-like) term
-    # L[t,j] = exp(cum_t - cum_j) for t >= j
+    # L[t,j] = exp(cum_t - cum_j) for t >= j.  Mask before the exp: above
+    # the diagonal diff > 0 overflows to inf at real widths, and the
+    # gradient of a where() taken after the exp is then 0 * inf = NaN.
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]         # (B,nc,Q,Q,H)
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(tri[None, None, :, :, None], jnp.exp(diff), 0.0)
+    L = jnp.exp(jnp.where(tri[None, None, :, :, None], diff, -jnp.inf))
     cb = jnp.einsum("bctn,bcjn->bctj", Cc, Bc)                   # (B,nc,Q,Q)
     scores = cb[..., None] * L * dtc[:, :, None, :, :]           # (B,nc,Q,Q,H)
     y_intra = jnp.einsum("bctjh,bcjhp->bcthp", scores, xc)

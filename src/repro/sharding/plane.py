@@ -15,9 +15,10 @@ Mesh axes:
 
 Divisibility follows the ``sanitize_spec`` rule: an axis whose size does
 not divide the corresponding plane dim degrades to replication for that
-dim (``R`` is always a multiple of ``SUBLANE = 8``, so row sharding holds
-for any rows axis up to 8; the DPU axis degrades whenever the live group
-size ``G`` is ragged).
+dim.  Rows are counted in ``SUBLANE = 8`` tiles, because the TPU kernels
+need every shard to hold whole tiles: the rows axis degrades unless it
+divides ``R / 8`` (the 176-row paper classifier splits 2 ways, not 4),
+and the DPU axis degrades whenever the live group size ``G`` is ragged.
 
 Bit-exactness contract (the ``shard-parity`` CI lane): with the default
 ``reduce="exact"`` mode, every sharded op and the sharded fused round are
@@ -38,12 +39,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import fedprox
 from repro.kernels import ops
-from repro.kernels.plane import LANE, as_plane
+from repro.kernels.plane import LANE, SUBLANE, as_plane
 from repro.sharding.specs import sanitize_spec
 
 DPU_AXIS = "dpu"
@@ -76,10 +76,12 @@ def plane_mesh(shape: Optional[Tuple[int, int]] = None) -> Mesh:
 
 def plane_axes(mesh: Mesh, n_lead: Optional[int], n_rows: int):
     """(dpu_axis_or_None, rows_axis_or_None) after the sanitize_spec
-    divisibility degradation for an (n_lead, n_rows, LANE) stack."""
+    divisibility degradation for an (n_lead, n_rows, LANE) stack.  Rows
+    split only into whole SUBLANE tiles: the TPU kernels need every
+    shard's row count to be a multiple of 8."""
     spec = sanitize_spec(P(DPU_AXIS, ROW_AXIS, None),
                          (n_lead if n_lead is not None else 0,
-                          n_rows, LANE), mesh)
+                          n_rows // SUBLANE, LANE), mesh)
     g_ax = spec[0] if n_lead is not None else None
     return g_ax, spec[1]
 
@@ -102,11 +104,11 @@ def _fedprox_accum_fn(mesh: Mesh, backend: str):
                                            act_l, eta_s, mu_s,
                                            backend=backend)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(stacked, stacked, anchor_spec, stacked,
                       P(g_ax), P(g_ax), P(), P()),
-            out_specs=(stacked, stacked), check_rep=False)(
+            out_specs=(stacked, stacked), check_vma=False)(
                 x, g, anchor, acc, coef, active, eta, mu)
 
     return jax.jit(fn)
@@ -139,10 +141,10 @@ def _nova_fn(mesh: Mesh, backend: str, reduce: str):
             return ops.nova_aggregate_plane(x_l, d_l, w_l, te,
                                             backend=backend)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(r_ax, None), P(g_ax, r_ax, None), P(g_ax), P()),
-            out_specs=P(r_ax, None), check_rep=False)(
+            out_specs=P(r_ax, None), check_vma=False)(
                 x, d_stack, weights, theta_eta)
 
     return jax.jit(fn)
@@ -178,10 +180,10 @@ def _robust_fn(mesh: Mesh, backend: str, mode: str, trim_frac: float):
                                               trim_frac=trim_frac,
                                               backend=backend)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(r_ax, None), P(g_ax, r_ax, None), P()),
-            out_specs=P(r_ax, None), check_rep=False)(x, d_stack, theta_eta)
+            out_specs=P(r_ax, None), check_vma=False)(x, d_stack, theta_eta)
 
     return jax.jit(fn)
 
@@ -291,13 +293,13 @@ def _sharded_round_fn(loss_fn, spec, mesh: Mesh, kernel_backend: str,
             # same compute graph as single-device, so bitwise identical
             return new, losses, eval_fn(spec.unflatten(gather_rows(new, 0)))
 
-        return shard_map(
+        return jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(stacked, master, data_specs, step_arr, step_arr,
                       P(None), P(), P(), per_dpu, P()),
             out_specs=(master, P(None, g_ax),
                        () if eval_fn is None else P()),
-            check_rep=False)(
+            check_vma=False)(
                 p0, anchor, data_stack, idx, weights, a, eta, mu,
                 w_abs, theta_eta)
 
@@ -348,8 +350,10 @@ from repro.analysis.jaxpr.contracts import Program, contract  # noqa: E402
 
 
 def _audit_nova_args(mesh: Mesh):
-    x = jnp.zeros((8, 1024), jnp.float32)
-    d_stack = jnp.ones((4, 8, 1024), jnp.float32)
+    # 16 rows = 2 SUBLANE tiles, so the (4, 2) mesh row-shards the plane
+    x = jnp.zeros((16, 1024), jnp.float32)
+    d_stack = jnp.ones((4, 16, 1024), jnp.float32)
+    assert plane_axes(mesh, 4, 16) == (DPU_AXIS, ROW_AXIS)
     weights = jnp.full((4,), 0.25, jnp.float32)
     return (x, d_stack, weights, jnp.asarray(0.05, jnp.float32))
 
@@ -383,7 +387,11 @@ def _nova_psum_contract():
 def _audit_sharded_round_program(reduce: str) -> Program:
     from repro.core import fedprox as _fp
     mesh = plane_mesh((4, 2))
-    spec, args = _fp._audit_round_args(n_group=4)
+    # 8,208 params -> a 16-row plane (2 tiles): rows really split 2 ways,
+    # so the FSDP gather_rows/my_rows path is traced
+    spec, args = _fp._audit_round_args(n_group=4, n_features=512,
+                                       n_classes=16)
+    assert plane_axes(mesh, 4, spec.rows) == (DPU_AXIS, ROW_AXIS)
     fn = _sharded_round_fn(_fp._audit_loss, spec, mesh, "cpu",
                            reduce=reduce)
     return Program(fn=fn, args=args)

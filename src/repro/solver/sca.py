@@ -181,7 +181,7 @@ from repro.analysis.jaxpr.contracts import Program, contract  # noqa: E402
     collectives={},
     forbid_f64=False,   # outer step mixes np host constants by design
     # jnp.sort/cumsum (simplex projections) are internally jitted
-    # single-eqn helpers in jax 0.4.37 — library noise, not our nesting
+    # single-eqn helpers — library noise, not our nesting
     fusion_allow=("sort", "cumsum"),
 )
 def _sca_step_contract():

@@ -154,6 +154,15 @@ def test_trimmed_mean_ignores_a_planted_outlier():
     assert np.abs(evil.mean(axis=0)).max() > 1e3
 
 
+def test_trimmed_mean_trims_a_nan_payload():
+    rng = np.random.RandomState(0)
+    honest = rng.randn(5, 2, 16).astype(np.float32)
+    evil = np.concatenate([honest, np.full((1, 2, 16), np.nan, np.float32)])
+    out = np.asarray(robust_reduce_ref(jnp.asarray(evil[::-1]), k=1))
+    np.testing.assert_allclose(
+        out, np.sort(honest, axis=0)[1:].mean(axis=0), rtol=1e-6)
+
+
 def test_robust_aggregate_plane_cpu_matches_interpret():
     rng = np.random.RandomState(1)
     x = rng.randn(8, 1024).astype(np.float32)

@@ -57,3 +57,17 @@ def test_ssd_grads_finite():
     g = jax.grad(lambda pp: jnp.sum(ssd_forward(pp, x, S_CFG) ** 2))(p)
     for leaf in jax.tree_util.tree_leaves(g):
         assert jnp.all(jnp.isfinite(leaf))
+
+
+def test_ssd_grads_finite_under_strong_decay():
+    """A 64-step chunk with fast decay, as at mamba2-130m's widths: the
+    intra-chunk exp above the diagonal overflows unless it is masked
+    before the exp, and its gradient is then NaN."""
+    import dataclasses
+    cfg = dataclasses.replace(S_CFG, chunk_size=64)
+    p, x = _setup(S=64)
+    p = dict(p, a_log=jnp.full_like(p["a_log"], np.log(24.0)),
+             dt_bias=jnp.full_like(p["dt_bias"], 2.0))
+    g = jax.grad(lambda pp: jnp.sum(ssd_forward(pp, x, cfg) ** 2))(p)
+    for leaf in jax.tree_util.tree_leaves(g):
+        assert jnp.all(jnp.isfinite(leaf))

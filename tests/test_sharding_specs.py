@@ -64,6 +64,11 @@ def test_plane_axes_divisibility_degradation():
     assert plane_axes(mesh, None, 16) == (None, "rows")
     # rows not divisible by the rows axis
     assert plane_axes(_mesh(dpu=4, rows=3), 8, 16) == ("dpu", None)
+    # rows split only into whole 8-row tiles: the 176-row paper_table1
+    # plane (22 tiles) splits 2 ways, not 4 (44 rows per shard)
+    assert plane_axes(_mesh(dpu=1, rows=2), 4, 176) == ("dpu", "rows")
+    assert plane_axes(_mesh(dpu=1, rows=4), 4, 176) == ("dpu", None)
+    assert plane_axes(_mesh(dpu=2, rows=2), 8, 8) == ("dpu", None)
 
 
 # -------------------------------------------------------- param rules ----
