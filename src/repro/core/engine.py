@@ -42,6 +42,7 @@ from repro.core.round_step import CEFLHyper, build_cefl_round_step
 from repro.kernels.plane import ParamPlane, as_plane, as_tree
 from repro.network.costs import network_costs, round_delay, round_energy
 from repro.scenario import get_scenario
+from repro.utils import tracing
 
 
 # ------------------------------------------------------- offloading -----
@@ -59,12 +60,13 @@ def realize_offloading(rng, data_per_ue: List[dict], w, net):
     if isinstance(w, RoundPlan):
         w = w.to_w()
     N, B, S = net.dims
-    rho_nb = np.asarray(w["rho_nb"])
-    rho_bs = np.asarray(w["rho_bs"])
+    rho_nb = tracing.sync(w["rho_nb"], "offload_plan")
+    rho_bs = tracing.sync(w["rho_bs"], "offload_plan")
     bs_pool_x, bs_pool_y = [[] for _ in range(B)], [[] for _ in range(B)]
     ue_data = []
     for n, d in enumerate(data_per_ue):
-        x, y = np.asarray(d["x"]), np.asarray(d["y"])
+        x = tracing.sync(d["x"], "offload_data")
+        y = tracing.sync(d["y"], "offload_data")
         D = len(y)
         if D == 0:
             ue_data.append({"x": jnp.asarray(x), "y": jnp.asarray(y)})
@@ -126,8 +128,9 @@ def realize_offloading(rng, data_per_ue: List[dict], w, net):
 # -------------------------------------------------------- executors -----
 
 def _plan_settings(plan: RoundPlan):
-    gammas = np.maximum(np.rint(np.asarray(plan.gamma)), 1).astype(int)
-    ms = np.clip(np.asarray(plan.m), 0.05, 1.0)
+    gammas = np.maximum(np.rint(tracing.sync(plan.gamma, "plan_settings")),
+                        1).astype(int)
+    ms = np.clip(tracing.sync(plan.m, "plan_settings"), 0.05, 1.0)
     return gammas, ms
 
 
@@ -264,6 +267,7 @@ class SimExecutor:
         gammas, ms = _plan_settings(plan)
         live = [(i, d) for i, d in enumerate(datasets)
                 if d is not None and len(d["y"])]
+        tracing.add("execute_round", live_dpus=len(live))
         if not live:
             out = (params, float("nan"))
             return out + (None,) if eval_fn is not None else out
@@ -281,6 +285,7 @@ class SimExecutor:
                     fedprox.batch_size(len(d["y"]), ms[i]))
                 groups.setdefault(
                     (int(gammas[i]), float(ms[i]), bucket), []).append(j)
+            tracing.add("execute_round", groups=len(groups))
             if (self.fuse_round and self.use_plane and len(groups) == 1
                     and agg in ("cefl", "fednova")
                     and not corrupt and robust_agg == "none"):
@@ -325,6 +330,7 @@ class SimExecutor:
                 for j, r in zip(idxs, out):
                     results[j] = r
         else:
+            tracing.add("execute_round", groups=len(live))
             for j, (i, d) in enumerate(live):
                 results[j] = fedprox.local_train(
                     params, loss_fn, d, gamma=int(gammas[i]),
@@ -333,8 +339,10 @@ class SimExecutor:
                     kernel_backend=self.kernel_backend)
         if corrupt:
             corrupt_local_results(results, live, corrupt, params, noise_key)
-        new_params = _aggregate(params, results, agg, eta=eta, theta=theta,
-                                robust=robust_agg, trim_frac=trim_frac)
+        with tracing.span("aggregate"):
+            new_params = _aggregate(params, results, agg, eta=eta,
+                                    theta=theta, robust=robust_agg,
+                                    trim_frac=trim_frac)
         mean_loss = weighted_mean([r.loss for r in results],
                                   [r.num_examples for r in results])
         if eval_fn is not None:
@@ -462,7 +470,8 @@ class MeshExecutor:
             # tau_eff never triggers recompilation (plane arithmetic only)
             new_params = plane.with_data(
                 plane.data + theta_val * (new_stack.data[0] - plane.data))
-            return new_params, float(metrics["loss"])
+            return new_params, float(tracing.sync(metrics["loss"],
+                                                  "mesh_loss"))
         stacked = jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), params)
         new_stack, metrics = step(stacked, batch, meta)
@@ -470,7 +479,8 @@ class MeshExecutor:
         # jit so per-round tau_eff never triggers recompilation
         new_params = jax.tree_util.tree_map(
             lambda p, p1: p + theta_val * (p1[0] - p), params, new_stack)
-        return new_params, float(metrics["loss"])
+        return new_params, float(tracing.sync(metrics["loss"],
+                                              "mesh_loss"))
 
 
 # ---------------------------------------------------- cohort sampling -----
@@ -479,18 +489,18 @@ def _gather_plan(plan: RoundPlan, cohort: np.ndarray, n_ue: int) -> RoundPlan:
     """Restrict a full-population plan to the cohort rows (the warm-start
     view handed to the solver, and the costing view of off-cadence
     rounds)."""
-    g = np.asarray(plan.gamma)
-    m = np.asarray(plan.m)
+    g = tracing.sync(plan.gamma, "cohort_plan")
+    m = tracing.sync(plan.m, "cohort_plan")
     return RoundPlan(
-        rho_nb=jnp.asarray(np.asarray(plan.rho_nb)[cohort]),
+        rho_nb=jnp.asarray(tracing.sync(plan.rho_nb, "cohort_plan")[cohort]),
         rho_bs=plan.rho_bs,
-        f_n=jnp.asarray(np.asarray(plan.f_n)[cohort]),
+        f_n=jnp.asarray(tracing.sync(plan.f_n, "cohort_plan")[cohort]),
         z_s=plan.z_s,
         gamma=jnp.asarray(np.concatenate([g[:n_ue][cohort], g[n_ue:]])),
         m=jnp.asarray(np.concatenate([m[:n_ue][cohort], m[n_ue:]])),
         I_s=plan.I_s,
-        I_nb=jnp.asarray(np.asarray(plan.I_nb)[cohort]),
-        I_bn=jnp.asarray(np.asarray(plan.I_bn)[:, cohort]),
+        I_nb=jnp.asarray(tracing.sync(plan.I_nb, "cohort_plan")[cohort]),
+        I_bn=jnp.asarray(tracing.sync(plan.I_bn, "cohort_plan")[:, cohort]),
         R_bs=plan.R_bs, delta_A=plan.delta_A, delta_R=plan.delta_R)
 
 
@@ -506,23 +516,24 @@ def _scatter_plan(sub: RoundPlan, cohort: np.ndarray, net,
     N, B, S = net.dims
     K = int(cohort.shape[0])
     rho_nb = np.zeros((N, B), np.float32)
-    rho_nb[cohort] = np.asarray(sub.rho_nb)
+    rho_nb[cohort] = tracing.sync(sub.rho_nb, "cohort_plan")
     f_n = np.full(N, net.cfg.f_min, np.float32)
-    f_n[cohort] = np.asarray(sub.f_n)
+    f_n[cohort] = tracing.sync(sub.f_n, "cohort_plan")
     gamma = np.full(N + S, float(opts.gamma_default), np.float32)
-    sg = np.asarray(sub.gamma)
+    sg = tracing.sync(sub.gamma, "cohort_plan")
     gamma[:N][cohort] = sg[:K]
     gamma[N:] = sg[K:]
     m = np.full(N + S, float(opts.m_default), np.float32)
-    sm = np.asarray(sub.m)
+    sm = tracing.sync(sub.m, "cohort_plan")
     m[:N][cohort] = sm[:K]
     m[N:] = sm[K:]
     I_nb = np.eye(B, dtype=np.float32)[
-        np.argmax(np.asarray(net.R_nb), axis=1)]
-    I_nb[cohort] = np.asarray(sub.I_nb)
+        np.argmax(tracing.sync(net.R_nb, "cohort_plan"), axis=1)]
+    I_nb[cohort] = tracing.sync(sub.I_nb, "cohort_plan")
     I_bn = np.zeros((B, N), np.float32)
-    I_bn[np.argmax(np.asarray(net.R_bn), axis=0), np.arange(N)] = 1.0
-    I_bn[:, cohort] = np.asarray(sub.I_bn)
+    I_bn[np.argmax(tracing.sync(net.R_bn, "cohort_plan"), axis=0),
+         np.arange(N)] = 1.0
+    I_bn[:, cohort] = tracing.sync(sub.I_bn, "cohort_plan")
     return RoundPlan(
         rho_nb=jnp.asarray(rho_nb), rho_bs=sub.rho_bs,
         f_n=jnp.asarray(f_n), z_s=sub.z_s,
@@ -620,7 +631,7 @@ class StagedRound:
     n_dc: int
     key: jax.Array
     events: object
-    t0: float
+    t0: float                      # perf_counter at begin_round's start
     # --- per-round client sampling (EngineOptions.cohort_size) ---
     cohort: Optional[np.ndarray] = None   # sorted drawn UE indices, or None
     sub_net: object = None                # topology.subnetwork view
@@ -735,14 +746,21 @@ class Engine:
         """Host side of round ``state.t``: scenario tick, cohort draw,
         plan decision, offloading realization, PRNG advance.  Mutates
         ``state`` (rng, key, plan) exactly as the solo loop does."""
+        with tracing.span("begin_round", round=state.t):
+            return self._begin_round(state, online_datasets)
+
+    def _begin_round(self, state: LoopState, online_datasets) -> StagedRound:
         opts = self.opts
         t = state.t
-        t0 = time.time()
+        t0 = time.perf_counter()
         # one scenario tick: evolved network (same cfg/dims -> the
         # solver's NetView pytree keeps hitting its compile cache),
         # drifted per-UE data, and the round's environment events
-        net_t, data_per_ue, events = self.scenario.step(
-            t, online_datasets, state.rng)
+        with tracing.span("scenario") as sp:
+            net_t, data_per_ue, events = self.scenario.step(
+                t, online_datasets, state.rng)
+            if tracing.enabled():
+                sp.set(h2d_bytes=tracing.nbytes(data_per_ue))
         N = len(data_per_ue)
         cohort = sub_net = sub_plan = None
         if opts.cohort_size is not None and opts.cohort_size < N:
@@ -768,25 +786,32 @@ class Engine:
             sub_net = subnetwork(net_t, cohort)
         D_bar = np.array([len(d["y"]) for d in data_per_ue], float)
         if state.plan is None or t % opts.reoptimize_every == 0:
-            if cohort is None:
-                state.plan = self.decide(net_t, D_bar, t,
-                                         prev_plan=state.plan)
-            else:
-                # gather -> solve the K-UE subproblem -> scatter.  A
-                # fixed K keeps hitting the solver's (K, B, S) compile
-                # cache no matter how large the population is.
-                sub_prev = None if state.plan is None else \
-                    _gather_plan(state.plan, cohort, N)
-                sub_plan = self.decide(
-                    sub_net, D_bar[cohort], t, prev_plan=sub_prev,
-                    consts=self._cohort_consts(N, cohort))
-                state.plan = _scatter_plan(sub_plan, cohort, net_t, opts)
-                if self.validate_plans:
-                    state.plan.validate(net_t)
+            with tracing.span("solve"):
+                if cohort is None:
+                    state.plan = self.decide(net_t, D_bar, t,
+                                             prev_plan=state.plan)
+                else:
+                    # gather -> solve the K-UE subproblem -> scatter.  A
+                    # fixed K keeps hitting the solver's (K, B, S)
+                    # compile cache no matter how large the population is.
+                    sub_prev = None if state.plan is None else \
+                        _gather_plan(state.plan, cohort, N)
+                    sub_plan = self.decide(
+                        sub_net, D_bar[cohort], t, prev_plan=sub_prev,
+                        consts=self._cohort_consts(N, cohort))
+                    state.plan = _scatter_plan(sub_plan, cohort, net_t,
+                                               opts)
+                    if self.validate_plans:
+                        state.plan.validate(net_t)
         elif cohort is not None:
             sub_plan = _gather_plan(state.plan, cohort, N)
-        ue_data, dc_data = realize_offloading(state.rng, data_per_ue,
-                                              state.plan, net_t)
+        with tracing.span("offload") as sp:
+            ue_data, dc_data = realize_offloading(state.rng, data_per_ue,
+                                                  state.plan, net_t)
+            if tracing.enabled():
+                sp.set(h2d_bytes=tracing.nbytes(ue_data, dc_data),
+                       rows=sum(len(d["y"]) for d in ue_data + dc_data
+                                if d is not None))
         state.key, sub = jax.random.split(state.key)
         return StagedRound(t=t, net_t=net_t, D_bar=D_bar, plan=state.plan,
                            datasets=ue_data + dc_data, n_dc=len(dc_data),
@@ -807,6 +832,11 @@ class Engine:
         fused its eval.  The single source of truth for the executor
         call: ``_run_loop``, the sweep executors, and the scenario fuzzer
         all route through here."""
+        with tracing.span("execute_round", round=staged.t):
+            return self._execute_round(state, staged, fuse_eval)
+
+    def _execute_round(self, state: LoopState, staged: StagedRound,
+                       fuse_eval: bool):
         opts = self.opts
         kw = {}
         corrupt = tuple(getattr(staged.events, "corrupted", ()) or ())
@@ -840,6 +870,11 @@ class Engine:
         """Account the finished round: costs, eval (per the cadence, or
         the precomputed ``acc`` a sweep executor hands in), report,
         callbacks.  Advances ``state.t``."""
+        with tracing.span("finish_round", round=staged.t):
+            return self._finish_round(state, staged, mean_loss, acc)
+
+    def _finish_round(self, state: LoopState, staged: StagedRound,
+                      mean_loss: float, acc: Optional[float]) -> RoundReport:
         plan = staged.plan
         scale = tuple(getattr(staged.events, "compute_scale", ()) or ())
         if staged.cohort is not None and staged.sub_plan is not None:
@@ -861,14 +896,18 @@ class Engine:
             w = dict(w)
             w["f_n"] = jnp.asarray(w["f_n"]) * jnp.asarray(
                 scale, jnp.float32)
-        costs = network_costs(w, cost_net, cost_D)
-        E = float(round_energy(costs, self.ow.xi3_sub))
-        Dl = float(round_delay(costs))
+        with tracing.span("costs"):
+            costs = network_costs(w, cost_net, cost_D)
+            E = float(tracing.sync(round_energy(costs, self.ow.xi3_sub),
+                                   "costs"))
+            Dl = float(tracing.sync(round_delay(costs), "costs"))
         state.cum_E += E
         state.cum_D += Dl
         if acc is None:
             if self.should_eval(staged.t):
-                acc = float(state.eval_fn(as_tree(state.params)))
+                with tracing.span("eval"):
+                    acc = float(tracing.sync(
+                        state.eval_fn(as_tree(state.params)), "eval"))
             else:
                 acc = state.last_acc
         state.last_acc = float(acc)
@@ -882,7 +921,7 @@ class Engine:
             dc_points=tuple(0 if d is None else len(d["y"])
                             for d in dc_data),
             gamma_mean=float(gammas.mean()), m_mean=float(ms.mean()),
-            plan=plan, wall_time=time.time() - staged.t0,
+            plan=plan, wall_time=time.perf_counter() - staged.t0,
             handovers=tuple(staged.events.handovers),
             aggregator_moved=(state.prev_agg is not None
                               and plan.aggregator != state.prev_agg),

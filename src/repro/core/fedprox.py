@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.kernels import ops
 from repro.kernels.plane import ParamPlane, as_plane
+from repro.utils import tracing
 
 
 def a_coefficients(gamma: int, eta: float, mu: float) -> jnp.ndarray:
@@ -203,26 +204,29 @@ def local_round_plane(params, loss_fn: Callable, datasets, *, gamma: int,
     plane = as_plane(params)
     spec = plane.spec
     G = len(datasets)
-    p0 = plane.broadcast(G).data
     Ds = [jax.tree_util.tree_leaves(d)[0].shape[0] for d in datasets]
     bszs = [batch_size(D, m_frac) for D in Ds]
     bucket = _bucket(max(bszs))
     assert all(_bucket(b) == bucket for b in bszs), \
         "grouping must put same-bucket DPUs together"
-    a = a_coefficients(gamma, eta, mu)
-    step_keys = jax.vmap(lambda k: jax.random.split(k, gamma))(
-        jnp.stack(keys))
-    data_stack, idx, weights = _stage_group_batches(datasets, step_keys, Ds,
-                                                    bucket, gamma, m_frac)
-    run = _plane_round_fn(loss_fn, spec, kernel_backend, eval_fn)
-    new_data, losses, acc = run(
-        p0, plane.data, data_stack, idx, weights, a,
-        jnp.asarray(eta, jnp.float32), jnp.asarray(mu, jnp.float32),
-        jnp.asarray(Ds, jnp.float32),
-        jnp.asarray(theta * eta, jnp.float32))
-    mean_loss = np.asarray(losses).mean(axis=0)         # (G,) — one sync
-    return (plane.with_data(new_data), mean_loss,
-            None if eval_fn is None else float(acc))
+    with tracing.span("group", G=G, gamma=gamma, bucket=bucket):
+        p0 = plane.broadcast(G).data
+        a = a_coefficients(gamma, eta, mu)
+        step_keys = jax.vmap(lambda k: jax.random.split(k, gamma))(
+            jnp.stack(keys))
+        data_stack, idx, weights = _stage_group_batches(
+            datasets, step_keys, Ds, bucket, gamma, m_frac)
+        run = _plane_round_fn(loss_fn, spec, kernel_backend, eval_fn)
+        with tracing.span("group_program"):
+            new_data, losses, acc = run(
+                p0, plane.data, data_stack, idx, weights, a,
+                jnp.asarray(eta, jnp.float32), jnp.asarray(mu, jnp.float32),
+                jnp.asarray(Ds, jnp.float32),
+                jnp.asarray(theta * eta, jnp.float32))
+        # (G,) — one sync
+        mean_loss = tracing.sync(losses, "group_losses").mean(axis=0)
+        return (plane.with_data(new_data), mean_loss,
+                None if eval_fn is None else float(tracing.sync(acc, "eval")))
 
 
 @functools.lru_cache(maxsize=512)
@@ -244,72 +248,85 @@ def _stage_group_batches(datasets, step_keys, Ds, bucket, gamma, m_frac):
     training scan — unlike the old host-side pre-gather, nothing here
     synchronizes on a device value, so staging costs O(G) async dispatches
     instead of O(G) blocking round-trips (the dominant term of the old
-    ``sim_round_plane_us`` profile)."""
+    ``sim_round_plane_us`` profile).
+
+    Runs in a ``cefl/stage_batches`` span that counts the bytes it
+    transfers (``h2d_bytes``) and the eager pads, stacks and
+    ``_choice_all_steps`` calls it issues (``dispatches``)."""
     G = len(datasets)
     Db = _bucket(max(Ds))
-    data_stack = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack([
-            jnp.pad(x, [(0, Db - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
-            for x in xs]), *datasets)
-    idx_cols = []
-    wts = np.zeros((gamma, G, bucket), np.float32)
-    for j in range(G):
-        bsz = batch_size(Ds[j], m_frac)
-        idx = _choice_all_steps(Ds[j], bsz)(step_keys[j])   # (gamma, bsz)
-        idx_cols.append(jnp.pad(idx, ((0, 0), (0, bucket - bsz))))
-        wts[:, j, :bsz] = 1.0
-    idx_all = jnp.stack(idx_cols, axis=1).astype(jnp.int32)
-    return data_stack, idx_all, jnp.asarray(wts)
+    with tracing.span("stage_batches") as sp:
+        data_stack = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack([
+                jnp.pad(x, [(0, Db - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
+                for x in xs]), *datasets)
+        idx_cols = []
+        wts = np.zeros((gamma, G, bucket), np.float32)
+        for j in range(G):
+            bsz = batch_size(Ds[j], m_frac)
+            idx = _choice_all_steps(Ds[j], bsz)(step_keys[j])  # (gamma, bsz)
+            idx_cols.append(jnp.pad(idx, ((0, 0), (0, bucket - bsz))))
+            wts[:, j, :bsz] = 1.0
+        idx_all = jnp.stack(idx_cols, axis=1).astype(jnp.int32)
+        weights = jnp.asarray(wts)
+        if tracing.enabled():
+            leaves = len(jax.tree_util.tree_leaves(datasets[0]))
+            sp.set(h2d_bytes=tracing.nbytes(weights),
+                   dispatches=leaves * (G + 1) + 2 * G + 1)
+    return data_stack, idx_all, weights
 
 
 def _local_train_batched_plane(params, loss_fn, datasets, *, gamma, m_frac,
                                eta, mu, keys, keep_planes=False,
                                anchors=None, kernel_backend="auto"):
     G = len(datasets)
-    if anchors is None:
-        plane = as_plane(params)
-        spec = plane.spec
-        p0 = plane.broadcast(G).data
-        anchor = plane.data
-    else:
-        planes = [as_plane(a) for a in anchors]
-        spec = planes[0].spec
-        assert all(p.spec == spec for p in planes), \
-            "multi-run groups must share one FlatSpec (same model)"
-        p0 = jnp.stack([p.data for p in planes], axis=0)
-        anchor = p0
-    Ds = [jax.tree_util.tree_leaves(d)[0].shape[0] for d in datasets]
-    bszs = [batch_size(D, m_frac) for D in Ds]
-    bucket = _bucket(max(bszs))
-    assert all(_bucket(b) == bucket for b in bszs), \
-        "grouping must put same-bucket DPUs together"
-    a = a_coefficients(gamma, eta, mu)
-    a1 = float(jnp.sum(a))
-    # one vmapped split for the whole group (same per-DPU streams as
-    # sequential `jax.random.split(k, gamma)` calls)
-    step_keys = jax.vmap(lambda k: jax.random.split(k, gamma))(
-        jnp.stack(keys))
-    data_stack, idx, weights = _stage_group_batches(datasets, step_keys, Ds,
-                                                    bucket, gamma, m_frac)
-    run = _plane_train_fn(loss_fn, spec,
-                          batched_anchor=anchors is not None,
-                          kernel_backend=kernel_backend)
-    p_stack, acc, losses = run(p0, anchor,
-                               data_stack, idx, weights, a,
-                               jnp.asarray(eta, jnp.float32),
-                               jnp.asarray(mu, jnp.float32))
-    d_stack = acc / a1
-    mean_loss = np.asarray(losses).mean(axis=0)         # (G,)
+    with tracing.span("group", G=G, gamma=gamma) as sp:
+        if anchors is None:
+            plane = as_plane(params)
+            spec = plane.spec
+            p0 = plane.broadcast(G).data
+            anchor = plane.data
+        else:
+            planes = [as_plane(a) for a in anchors]
+            spec = planes[0].spec
+            assert all(p.spec == spec for p in planes), \
+                "multi-run groups must share one FlatSpec (same model)"
+            p0 = jnp.stack([p.data for p in planes], axis=0)
+            anchor = p0
+        Ds = [jax.tree_util.tree_leaves(d)[0].shape[0] for d in datasets]
+        bszs = [batch_size(D, m_frac) for D in Ds]
+        bucket = _bucket(max(bszs))
+        assert all(_bucket(b) == bucket for b in bszs), \
+            "grouping must put same-bucket DPUs together"
+        sp.set(bucket=bucket)
+        a = a_coefficients(gamma, eta, mu)
+        a1 = float(tracing.sync(jnp.sum(a), "a_norm"))
+        # one vmapped split for the whole group (same per-DPU streams as
+        # sequential `jax.random.split(k, gamma)` calls)
+        step_keys = jax.vmap(lambda k: jax.random.split(k, gamma))(
+            jnp.stack(keys))
+        data_stack, idx, weights = _stage_group_batches(
+            datasets, step_keys, Ds, bucket, gamma, m_frac)
+        run = _plane_train_fn(loss_fn, spec,
+                              batched_anchor=anchors is not None,
+                              kernel_backend=kernel_backend)
+        with tracing.span("group_program"):
+            p_stack, acc, losses = run(p0, anchor,
+                                       data_stack, idx, weights, a,
+                                       jnp.asarray(eta, jnp.float32),
+                                       jnp.asarray(mu, jnp.float32))
+        d_stack = acc / a1
+        mean_loss = tracing.sync(losses, "group_losses").mean(axis=0)  # (G,)
 
-    def view(stack, j):
-        p = ParamPlane(data=stack[j], spec=spec)
-        return p if keep_planes else p.to_tree()
+        def view(stack, j):
+            p = ParamPlane(data=stack[j], spec=spec)
+            return p if keep_planes else p.to_tree()
 
-    return [LocalResult(
-        params=view(p_stack, j), d_i=view(d_stack, j),
-        num_examples=Ds[j], gamma=gamma,
-        sgd_flops=float(gamma) * m_frac * Ds[j],
-        loss=float(mean_loss[j])) for j in range(G)]
+        return [LocalResult(
+            params=view(p_stack, j), d_i=view(d_stack, j),
+            num_examples=Ds[j], gamma=gamma,
+            sgd_flops=float(gamma) * m_frac * Ds[j],
+            loss=float(mean_loss[j])) for j in range(G)]
 
 
 # ------------------------------------------------ tree reference path -----
@@ -338,7 +355,7 @@ def _local_train_tree(params, loss_fn, data, *, gamma, m_frac, eta, mu,
     anchor = params
     D = jax.tree_util.tree_leaves(data)[0].shape[0]
     a = a_coefficients(gamma, eta, mu)
-    a1 = float(jnp.sum(a))
+    a1 = float(tracing.sync(jnp.sum(a), "a_norm"))
     step = _prox_step_fn(loss_fn)
     acc = jax.tree_util.tree_map(jnp.zeros_like, params)
     keys = jax.random.split(key, gamma)
@@ -346,7 +363,8 @@ def _local_train_tree(params, loss_fn, data, *, gamma, m_frac, eta, mu,
     mu_j = jnp.asarray(mu, jnp.float32)
     loss_sum = 0.0
     for k in range(gamma):
-        idx = np.asarray(sample_minibatch(keys[k], D, m_frac))
+        idx = tracing.sync(sample_minibatch(keys[k], D, m_frac),
+                           "minibatch_idx")
         bsz = _bucket(len(idx))
         pad = np.concatenate([idx, np.zeros(bsz - len(idx), idx.dtype)])
         weights = jnp.asarray(
@@ -354,7 +372,7 @@ def _local_train_tree(params, loss_fn, data, *, gamma, m_frac, eta, mu,
             jnp.float32)
         batch = jax.tree_util.tree_map(lambda x: x[pad], data)
         params, gF, loss = step(params, anchor, batch, weights, eta_j, mu_j)
-        loss_sum += float(loss)
+        loss_sum += float(tracing.sync(loss, "step_loss"))
         acc = jax.tree_util.tree_map(
             lambda acU, g: acU + a[k] * g, acc, gF)       # eq. (10) numerator
     d_i = jax.tree_util.tree_map(lambda x: x / a1, acc)
@@ -386,7 +404,7 @@ def _local_train_batched_tree(params, loss_fn, datasets, *, gamma, m_frac,
     assert all(_bucket(b) == bucket for b in bszs), \
         "grouping must put same-bucket DPUs together"
     a = a_coefficients(gamma, eta, mu)
-    a1 = float(jnp.sum(a))
+    a1 = float(tracing.sync(jnp.sum(a), "a_norm"))
     step = _prox_step_batched_fn(loss_fn)
     p_stack = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (G,) + x.shape), params)
@@ -399,7 +417,9 @@ def _local_train_batched_tree(params, loss_fn, datasets, *, gamma, m_frac,
     for k in range(gamma):
         micro, wts = [], []
         for j, d in enumerate(datasets):
-            idx = np.asarray(sample_minibatch(step_keys[j][k], Ds[j], m_frac))
+            idx = tracing.sync(
+                sample_minibatch(step_keys[j][k], Ds[j], m_frac),
+                "minibatch_idx")
             pad = np.concatenate([idx, np.zeros(bucket - len(idx), idx.dtype)])
             wts.append(np.concatenate([np.ones(len(idx)),
                                        np.zeros(bucket - len(idx))]))
@@ -408,7 +428,7 @@ def _local_train_batched_tree(params, loss_fn, datasets, *, gamma, m_frac,
         weights = jnp.asarray(np.stack(wts), jnp.float32)
         p_stack, gF, losses = step(p_stack, anchor, batch, weights,
                                    eta_j, mu_j)
-        loss_sum += np.asarray(losses)
+        loss_sum += tracing.sync(losses, "step_loss")
         acc = jax.tree_util.tree_map(
             lambda acU, g: acU + a[k] * g, acc, gF)
     d_stack = jax.tree_util.tree_map(lambda x: x / a1, acc)

@@ -55,6 +55,7 @@ from repro.kernels import ref as _ref
 from repro.kernels import robust_aggregate as _ra
 from repro.kernels.plane import FlatSpec, ParamPlane, spec_of  # noqa: F401
 from repro.kernels.tiling import TilePlan, plan_tiles  # noqa: F401
+from repro.utils import tracing
 
 # NOTE: no serving-kernel imports here.  ops.py is on the import path of
 # every training module, and swa_decode_attention is a pure re-export
@@ -211,43 +212,49 @@ def fedprox_accum_plane(x, g, anchor, acc, coef, active, eta, mu, *,
                         interpret: Optional[bool] = None,
                         backend: Optional[str] = None):
     """Batched proximal step + eq.-10 accumulation on (G, R, LANE) planes
-    (one launch per local iteration for a whole DPU group)."""
+    (one launch per local iteration for a whole DPU group), under the
+    ``cefl.eq10`` named scope."""
     b = resolve_backend(backend, interpret)
-    if b == "cpu":
-        coef = jnp.asarray(coef, jnp.float32)
-        active = jnp.asarray(active, jnp.float32)
-        if _tracing(x, g, anchor, acc, coef, active):
-            return _ref.fedprox_accum_ref(x, g, anchor, acc, coef, active,
-                                          eta, mu)
-        return _fedprox_accum_cpu(x, g, anchor, acc, coef, active, eta, mu)
-    # resident blocks per grid step: x, g, anchor, acc, x_new, acc_new
-    plan = _plan_for(b, x.shape[1], x.shape[2], n_operands=6, dtype=x.dtype)
-    return _fp.fedprox_accum_2d(x, g, anchor, acc, coef, active, eta, mu,
-                                interpret=(b == "interpret"), plan=plan)
+    with jax.named_scope(tracing.EQ10):
+        if b == "cpu":
+            coef = jnp.asarray(coef, jnp.float32)
+            active = jnp.asarray(active, jnp.float32)
+            if _tracing(x, g, anchor, acc, coef, active):
+                return _ref.fedprox_accum_ref(x, g, anchor, acc, coef,
+                                              active, eta, mu)
+            return _fedprox_accum_cpu(x, g, anchor, acc, coef, active, eta,
+                                      mu)
+        # resident blocks per grid step: x, g, anchor, acc, x_new, acc_new
+        plan = _plan_for(b, x.shape[1], x.shape[2], n_operands=6,
+                         dtype=x.dtype)
+        return _fp.fedprox_accum_2d(x, g, anchor, acc, coef, active, eta, mu,
+                                    interpret=(b == "interpret"), plan=plan)
 
 
 def nova_aggregate_plane(x, d_stack, weights, theta_eta, *,
                          interpret: Optional[bool] = None,
                          backend: Optional[str] = None):
     """eq. 11 on planes.  ``weights`` must already be normalized.  ``x``
-    may be (R, LANE) or (n_dpu, R, LANE) (stacked per-DPU replicas)."""
+    may be (R, LANE) or (n_dpu, R, LANE) (stacked per-DPU replicas).
+    Runs under the ``cefl.eq11`` named scope."""
     b = resolve_backend(backend, interpret)
-    if b == "cpu":
-        w32 = jnp.asarray(weights, jnp.float32)
-        if _tracing(x, d_stack, w32):
-            return _ref.nova_aggregate_ref(x, d_stack, w32, theta_eta)
-        return _nova_plane_cpu(x, d_stack, w32, theta_eta)
-    n = d_stack.shape[0]
-    itp = b == "interpret"
-    if x.ndim == 3:
-        # resident: x/out keep the n-stack, d streams one tile, + scratch
-        plan = _plan_for(b, x.shape[1], x.shape[2],
-                         n_operands=2 * n + 2, dtype=x.dtype)
-        return _na.nova_aggregate_stacked_2d(x, d_stack, weights, theta_eta,
-                                             interpret=itp, plan=plan)
-    plan = _plan_for(b, *x.shape, n_operands=4, dtype=x.dtype)
-    return _na.nova_aggregate_2d(x, d_stack, weights, theta_eta,
-                                 interpret=itp, plan=plan)
+    with jax.named_scope(tracing.EQ11):
+        if b == "cpu":
+            w32 = jnp.asarray(weights, jnp.float32)
+            if _tracing(x, d_stack, w32):
+                return _ref.nova_aggregate_ref(x, d_stack, w32, theta_eta)
+            return _nova_plane_cpu(x, d_stack, w32, theta_eta)
+        n = d_stack.shape[0]
+        itp = b == "interpret"
+        if x.ndim == 3:
+            # resident: x/out keep the n-stack, d streams one tile, + scratch
+            plan = _plan_for(b, x.shape[1], x.shape[2],
+                             n_operands=2 * n + 2, dtype=x.dtype)
+            return _na.nova_aggregate_stacked_2d(
+                x, d_stack, weights, theta_eta, interpret=itp, plan=plan)
+        plan = _plan_for(b, *x.shape, n_operands=4, dtype=x.dtype)
+        return _na.nova_aggregate_2d(x, d_stack, weights, theta_eta,
+                                     interpret=itp, plan=plan)
 
 
 def robust_aggregate_plane(x, d_stack, theta_eta, *,

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import SSMConfig
+from repro.utils import tracing
 
 
 def mamba_dims(d_model: int, s: SSMConfig):
@@ -79,7 +80,13 @@ def ssd_forward(params: dict, x_in: jnp.ndarray, s: SSMConfig,
                 init_state: Optional[dict] = None,
                 return_state: bool = False):
     """Chunked SSD. x_in: (B, S, d_model); S % chunk == 0.
-    Returns y (B,S,d_model) and optionally {"h":..., "conv":...}."""
+    Returns y (B,S,d_model) and optionally {"h":..., "conv":...}.
+    Its ops, and their gradients, carry the ``cefl.ssd`` named scope."""
+    with jax.named_scope(tracing.SSD):
+        return _ssd_forward(params, x_in, s, init_state, return_state)
+
+
+def _ssd_forward(params, x_in, s, init_state, return_state):
     B, S, d_model = x_in.shape
     d_inner, H, d_conv = mamba_dims(d_model, s)
     N, P, Q = s.state_dim, s.head_dim, s.chunk_size

@@ -45,6 +45,7 @@ from repro.core import fedprox
 from repro.kernels import ops
 from repro.kernels.plane import LANE, SUBLANE, as_plane
 from repro.sharding.specs import sanitize_spec
+from repro.utils import tracing
 
 DPU_AXIS = "dpu"
 ROW_AXIS = "rows"
@@ -104,12 +105,13 @@ def _fedprox_accum_fn(mesh: Mesh, backend: str):
                                            act_l, eta_s, mu_s,
                                            backend=backend)
 
-        return jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(stacked, stacked, anchor_spec, stacked,
-                      P(g_ax), P(g_ax), P(), P()),
-            out_specs=(stacked, stacked), check_vma=False)(
-                x, g, anchor, acc, coef, active, eta, mu)
+        with jax.named_scope(tracing.EQ10):
+            return jax.shard_map(
+                body, mesh=mesh,
+                in_specs=(stacked, stacked, anchor_spec, stacked,
+                          P(g_ax), P(g_ax), P(), P()),
+                out_specs=(stacked, stacked), check_vma=False)(
+                    x, g, anchor, acc, coef, active, eta, mu)
 
     return jax.jit(fn)
 
@@ -141,11 +143,13 @@ def _nova_fn(mesh: Mesh, backend: str, reduce: str):
             return ops.nova_aggregate_plane(x_l, d_l, w_l, te,
                                             backend=backend)
 
-        return jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(r_ax, None), P(g_ax, r_ax, None), P(g_ax), P()),
-            out_specs=P(r_ax, None), check_vma=False)(
-                x, d_stack, weights, theta_eta)
+        with jax.named_scope(tracing.EQ11):
+            return jax.shard_map(
+                body, mesh=mesh,
+                in_specs=(P(r_ax, None), P(g_ax, r_ax, None), P(g_ax),
+                          P()),
+                out_specs=P(r_ax, None), check_vma=False)(
+                    x, d_stack, weights, theta_eta)
 
     return jax.jit(fn)
 
@@ -274,19 +278,21 @@ def _sharded_round_fn(loss_fn, spec, mesh: Mesh, kernel_backend: str,
             (_p, acc), losses = jax.lax.scan(
                 body, (p0_l, acc0), (idx_l, w_l, a_l))
             d = acc / jnp.sum(a_l)
-            if reduce == "psum" and g_ax is not None:
-                s = jnp.sum(jax.lax.all_gather(wabs_l, DPU_AXIS,
-                                               tiled=True))
-                part = jnp.einsum("g,grl->rl", wabs_l / s, d)
-                new = anchor_l - te_s * jax.lax.psum(part, DPU_AXIS)
-            else:
-                if g_ax is not None:
-                    d = jax.lax.all_gather(d, DPU_AXIS, axis=0, tiled=True)
-                    wabs_l = jax.lax.all_gather(wabs_l, DPU_AXIS,
-                                                tiled=True)
-                w = wabs_l / jnp.sum(wabs_l)   # the single normalization
-                new = ops.nova_aggregate_plane(anchor_l, d, w, te_s,
-                                               backend=backend)
+            with jax.named_scope(tracing.EQ11):
+                if reduce == "psum" and g_ax is not None:
+                    s = jnp.sum(jax.lax.all_gather(wabs_l, DPU_AXIS,
+                                                   tiled=True))
+                    part = jnp.einsum("g,grl->rl", wabs_l / s, d)
+                    new = anchor_l - te_s * jax.lax.psum(part, DPU_AXIS)
+                else:
+                    if g_ax is not None:
+                        d = jax.lax.all_gather(d, DPU_AXIS, axis=0,
+                                               tiled=True)
+                        wabs_l = jax.lax.all_gather(wabs_l, DPU_AXIS,
+                                                    tiled=True)
+                    w = wabs_l / jnp.sum(wabs_l)  # the single normalization
+                    new = ops.nova_aggregate_plane(anchor_l, d, w, te_s,
+                                                   backend=backend)
             if eval_fn is None:
                 return new, losses, ()
             # eval on the gathered full plane, redundantly per shard —
@@ -321,27 +327,29 @@ def local_round_plane_sharded(params, loss_fn, datasets, *, gamma: int,
     plane = as_plane(params)
     spec = plane.spec
     G = len(datasets)
-    p0 = plane.broadcast(G).data
     Ds = [jax.tree_util.tree_leaves(d)[0].shape[0] for d in datasets]
     bszs = [fedprox.batch_size(D, m_frac) for D in Ds]
     bucket = fedprox._bucket(max(bszs))
     assert all(fedprox._bucket(b) == bucket for b in bszs), \
         "grouping must put same-bucket DPUs together"
-    a = fedprox.a_coefficients(gamma, eta, mu)
-    step_keys = jax.vmap(lambda k: jax.random.split(k, gamma))(
-        jnp.stack(keys))
-    data_stack, idx, weights = fedprox._stage_group_batches(
-        datasets, step_keys, Ds, bucket, gamma, m_frac)
-    run = _sharded_round_fn(loss_fn, spec, mesh, kernel_backend, eval_fn,
-                            reduce)
-    new_data, losses, acc = run(
-        p0, plane.data, data_stack, idx, weights, a,
-        jnp.asarray(eta, jnp.float32), jnp.asarray(mu, jnp.float32),
-        jnp.asarray(Ds, jnp.float32),
-        jnp.asarray(theta * eta, jnp.float32))
-    mean_loss = np.asarray(losses).mean(axis=0)
-    return (plane.with_data(new_data), mean_loss,
-            None if eval_fn is None else float(acc))
+    with tracing.span("group", G=G, gamma=gamma, bucket=bucket):
+        p0 = plane.broadcast(G).data
+        a = fedprox.a_coefficients(gamma, eta, mu)
+        step_keys = jax.vmap(lambda k: jax.random.split(k, gamma))(
+            jnp.stack(keys))
+        data_stack, idx, weights = fedprox._stage_group_batches(
+            datasets, step_keys, Ds, bucket, gamma, m_frac)
+        run = _sharded_round_fn(loss_fn, spec, mesh, kernel_backend,
+                                eval_fn, reduce)
+        with tracing.span("group_program"):
+            new_data, losses, acc = run(
+                p0, plane.data, data_stack, idx, weights, a,
+                jnp.asarray(eta, jnp.float32), jnp.asarray(mu, jnp.float32),
+                jnp.asarray(Ds, jnp.float32),
+                jnp.asarray(theta * eta, jnp.float32))
+        mean_loss = tracing.sync(losses, "group_losses").mean(axis=0)
+        return (plane.with_data(new_data), mean_loss,
+                None if eval_fn is None else float(tracing.sync(acc, "eval")))
 
 
 # ---------------------------------------------------- trace contracts --

@@ -32,6 +32,7 @@ from repro.solver.objective import (ObjectiveWeights, apply_required_deltas,
                                     objective, objective_breakdown)
 from repro.solver.primal_dual import PDHyper, make_surrogate
 from repro.solver.ref import SCAResult  # noqa: F401  (public re-export)
+from repro.utils import tracing
 
 if TYPE_CHECKING:   # annotation-only: keeps repro.solver import-cycle free
     from repro.core.convergence import MLConstants
@@ -100,14 +101,16 @@ def _solve_jit(net, D_bar, consts: MLConstants, ow: ObjectiveWeights,
 
     step = _outer_step(spec.dims, pd, ow, _consts_scalars(consts),
                        distributed, zeta)
-    hist = [float(objective(w_phys, net, D_bar, consts, ow))]
+    hist = [float(tracing.sync(objective(w_phys, net, D_bar, consts, ow),
+                               "sca_objective"))]
     viol = []
     ell = 0
     for ell in range(max_outer):
-        w, Lambda, obj, max_viol = step(w, Lambda, nv, D_j, theta_i,
-                                        sigma_i, scale_flat, W_cons)
-        obj = float(obj)
-        viol.append(float(max_viol))
+        with tracing.span("sca_outer", iter=ell):
+            w, Lambda, obj, max_viol = step(w, Lambda, nv, D_j, theta_i,
+                                            sigma_i, scale_flat, W_cons)
+            obj = float(tracing.sync(obj, "sca_objective"))
+            viol.append(float(tracing.sync(max_viol, "sca_violation")))
         improved = hist[-1] - obj
         hist.append(obj)
         if 0 <= improved < tol * max(1.0, abs(hist[0])):
@@ -134,13 +137,15 @@ def select_aggregator(w: Dict, net, D_bar, consts, ow) -> int:
     minimizes the true objective.  This is what makes the aggregation
     point actually *float* round-to-round under dynamic scenarios.
     """
-    S = int(np.asarray(w["I_s"]).shape[0])
     objs = []
-    for s in range(S):
-        ws = dict(w)
-        ws["I_s"] = jax.nn.one_hot(jnp.asarray(s), S)
-        ws = apply_required_deltas(ws, net, D_bar)
-        objs.append(float(objective(ws, net, D_bar, consts, ow)))
+    with tracing.span("select_aggregator"):
+        S = int(tracing.sync(w["I_s"], "select_aggregator").shape[0])
+        for s in range(S):
+            ws = dict(w)
+            ws["I_s"] = jax.nn.one_hot(jnp.asarray(s), S)
+            ws = apply_required_deltas(ws, net, D_bar)
+            objs.append(float(tracing.sync(
+                objective(ws, net, D_bar, consts, ow), "select_aggregator")))
     return int(np.argmin(objs))
 
 
@@ -157,18 +162,22 @@ def solve(net, D_bar, consts: MLConstants, ow: ObjectiveWeights,
     """
     pd = pd or PDHyper()
     if backend == "ref":
-        return _ref.solve(net, D_bar, consts, ow, zeta=zeta,
-                          max_outer=max_outer, tol=tol, pd=pd,
-                          distributed=distributed, w0=w0, seed=seed)
-    if backend != "jit":
+        res = _ref.solve(net, D_bar, consts, ow, zeta=zeta,
+                         max_outer=max_outer, tol=tol, pd=pd,
+                         distributed=distributed, w0=w0, seed=seed)
+    elif backend == "jit":
+        if w0 is not None:
+            w0 = {k: jnp.asarray(tracing.sync(v, "sca_warm_start"),
+                                 jnp.float32) for k, v in w0.items()}
+        res = _solve_jit(net, D_bar, consts, ow, zeta=zeta,
+                         max_outer=max_outer, tol=tol, pd=pd,
+                         distributed=distributed, w0=w0)
+    else:
         raise ValueError(f"unknown solver backend {backend!r} "
                          "(expected 'jit' or 'ref')")
-    if w0 is not None:
-        w0 = {k: jnp.asarray(np.asarray(v), jnp.float32)
-              for k, v in w0.items()}
-    return _solve_jit(net, D_bar, consts, ow, zeta=zeta,
-                      max_outer=max_outer, tol=tol, pd=pd,
-                      distributed=distributed, w0=w0)
+    # the outer iterations of the engine's enclosing cefl/solve span
+    tracing.add("solve", outer_iters=res.iterations)
+    return res
 
 
 # ----------------------------------------------------- trace contract --

@@ -98,3 +98,21 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, size):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel,scope", [
+    ("fedprox_accum", "cefl.eq10"), ("nova_aggregate", "cefl.eq11"),
+    ("nova_aggregate_stacked", "cefl.eq11")])
+def test_named_scopes_keep_the_kernel_names(one_chip, kernel, scope):
+    """The eq.-10/11 scopes label the kernels' ops (``op_name``) and leave
+    the custom call named after its kernel, which trace readers key on."""
+    R, G = _plane_dims("paper_table1")
+    fn, shapes = _program(kernel, R, G)
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    assert calls and all(
+        line.split(" = ", 1)[0].split("%")[-1].startswith(f"{kernel}_2d.")
+        for line in calls)
+    assert all(f"/{scope}/" in line for line in calls)
