@@ -11,7 +11,6 @@ registered under the name the old ``core/cefl.py`` string dispatch used:
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -49,18 +48,14 @@ class CEFLStrategy:
             for k, ax in (("I_s", 0), ("I_nb", 1), ("I_bn", 0)):
                 x = jnp.asarray(w0[k], jnp.float32)
                 w0[k] = 0.5 * x + 0.5 / x.shape[ax]
-        D_j = jnp.asarray(D_bar, jnp.float32)
-        res = sca.solve(net, D_j, ctx.consts, ctx.ow,
-                        max_outer=opts.solver_outer,
+        res = sca.solve(net, jnp.asarray(D_bar, jnp.float32), ctx.consts,
+                        ctx.ow, max_outer=opts.solver_outer,
                         distributed=opts.distributed_solver, w0=w0,
                         backend=opts.solver_backend)
-        # floating aggregation point: exact enumeration over the rounded
-        # plan (argmax of a near-uniform relaxed I_s is noise)
-        w = dict(res.w_rounded)
-        s = sca.select_aggregator(w, net, D_j, ctx.consts, ctx.ow)
-        w["I_s"] = jax.nn.one_hot(jnp.asarray(s), w["I_s"].shape[0])
-        w = apply_required_deltas(w, net, D_j)
-        return RoundPlan.from_w(w)
+        # floating aggregation point: the solver enumerates the S one-hot
+        # I_s over its rounded plan (argmax of a near-uniform relaxed I_s
+        # is noise) and hands back the least candidate's plan
+        return RoundPlan.from_w(res.plan)
 
 
 class _GreedyBase:

@@ -91,13 +91,31 @@ def apply_required_deltas(w: Dict, net, D_bar, slack: float = 1.0) -> Dict:
     return w
 
 
-def objective_breakdown(w, net, D_bar, consts, ow):
+BREAKDOWN_TERMS = ("ml", "delay", "delta_A_req", "delta_R_req", "energy",
+                   "total")
+
+
+def breakdown_terms(w, net, D_bar, consts, ow) -> Dict:
+    """The terms of J(w) as arrays, keyed by ``BREAKDOWN_TERMS``.
+    Traceable: the jitted solver computes them inside its finish."""
     costs = C.network_costs(w, net, D_bar)
     return {
-        "ml": float(ml_bound(w, net, D_bar, consts, ow)),
-        "delay": float(w["delta_A"] + w["delta_R"]),
-        "delay_required": (float(costs["delta_A_req"]),
-                           float(costs["delta_R_req"])),
-        "energy": float(C.round_energy(costs, ow.xi3_sub)),
-        "total": float(objective(w, net, D_bar, consts, ow)),
+        "ml": ml_bound(w, net, D_bar, consts, ow),
+        "delay": w["delta_A"] + w["delta_R"],
+        "delta_A_req": costs["delta_A_req"],
+        "delta_R_req": costs["delta_R_req"],
+        "energy": C.round_energy(costs, ow.xi3_sub),
+        "total": objective(w, net, D_bar, consts, ow),
     }
+
+
+def breakdown_dict(terms: Dict) -> Dict:
+    """The host-side breakdown (floats) of ``breakdown_terms``' values."""
+    f = {k: float(terms[k]) for k in BREAKDOWN_TERMS}
+    return {"ml": f["ml"], "delay": f["delay"],
+            "delay_required": (f["delta_A_req"], f["delta_R_req"]),
+            "energy": f["energy"], "total": f["total"]}
+
+
+def objective_breakdown(w, net, D_bar, consts, ow):
+    return breakdown_dict(breakdown_terms(w, net, D_bar, consts, ow))

@@ -226,7 +226,7 @@ def test_syncs_count_every_read_back_site(traced):
     assert sites == {
         "sca_warm_start": n_plan,
         "sca_objective": iters + 2, "sca_violation": iters,
-        "select_aggregator": 2 * (N_DC + 1),
+        "select_aggregator": 2,        # one read-back a solve
         "offload_plan": 2 * 2, "offload_data": 2 * 2 * N_UE,
         "plan_settings": 2 * 4, "a_norm": 2 * 2, "group_losses": 2 * 2,
         "costs": 2 * 2, "eval": 2}
