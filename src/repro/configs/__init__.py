@@ -22,6 +22,7 @@ ARCH_IDS = [
     "starcoder2-15b",
     "llama4-maverick-400b-a17b",
     "llama3-405b",
+    "granite-4.0-h-micro",
 ]
 
 _MODULES = {
@@ -35,6 +36,7 @@ _MODULES = {
     "starcoder2-15b": "starcoder2_15b",
     "llama4-maverick-400b-a17b": "llama4_maverick_400b_a17b",
     "llama3-405b": "llama3_405b",
+    "granite-4.0-h-micro": "granite4_h_micro",
     "cefl-paper": "cefl_paper",
 }
 

@@ -65,6 +65,13 @@ class ModelConfig:
     dtype: str = "bfloat16"
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # --- muP multipliers and positions (granite-4.0-h); each default leaves
+    # the program as it is: a factor of 1 is not applied at all ---
+    embedding_multiplier: float = 1.0         # token embeddings times this
+    residual_multiplier: float = 1.0          # each residual branch times this
+    attention_multiplier: Optional[float] = None  # softmax scale; None: 1/sqrt(head_dim)
+    logits_scaling: float = 1.0               # logits divided by this
+    nope: bool = False                        # no positional embedding (no RoPE)
     # citation for the config values
     source: str = ""
 

@@ -9,6 +9,7 @@ shim over this function.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import jax
@@ -40,6 +41,10 @@ def run_lm(spec: ExperimentSpec, *, seed=None, checkpoint=None,
     cfg = get_config(m.arch)
     if m.reduced:
         cfg = reduced(cfg)
+    if m.layers:
+        cfg = dataclasses.replace(cfg, num_layers=m.layers)
+    if m.vocab:
+        cfg = dataclasses.replace(cfg, vocab_size=m.vocab)
     if verbose:
         print(f"[train] {cfg.name}: {cfg.param_count()/1e6:.1f}M params, "
               f"{m.n_dpu} DPUs x gamma={m.gamma}")
@@ -49,6 +54,7 @@ def run_lm(spec: ExperimentSpec, *, seed=None, checkpoint=None,
         # flat-plane hot path: params stay (n_dpu, R, LANE) for the whole
         # run; the tree view is materialized only at the checkpoint
         params = ParamPlane.from_tree(params0).broadcast(m.n_dpu)
+        del params0          # the planes hold the weights from here on
     else:
         params = jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x[None], (m.n_dpu,) + x.shape),

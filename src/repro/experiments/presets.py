@@ -138,6 +138,22 @@ def lm_mamba2_130m() -> ExperimentSpec:
         strategy="fixed:0", scenario="static", seeds=(0,))
 
 
+@register_experiment("lm_granite4_h_micro")
+def lm_granite4_h_micro() -> ExperimentSpec:
+    """granite-4.0-h-micro at published widths, one chip's share of a DPU
+    that trains it on four: one period of ten layers (nine Mamba-2, one
+    NoPE GQA attention) and a quarter of the vocabulary's rows, 798M
+    parameters; one DPU x gamma 2 on 4,096-token sequences (the
+    ``granite4_h_micro.hybrid_4k`` benchmark cell)."""
+    return ExperimentSpec(
+        name="lm_granite4_h_micro",
+        model=ModelSpec(kind="lm", arch="granite-4.0-h-micro", reduced=False,
+                        batch=1, seq=4096, n_dpu=1, n_micro=1, gamma=2,
+                        layers=10, vocab=25088),
+        engine=EngineSpec(rounds=200, eta=3e-2, mu=0.01),
+        strategy="fixed:0", scenario="static", seeds=(0,))
+
+
 @register_experiment("bench_quick")
 def bench_quick() -> ExperimentSpec:
     """The QUICK=1 benchmark harness cell (``benchmarks/common.setup``):

@@ -48,6 +48,12 @@ class ModelSpec:
     n_dpu: int = 2
     n_micro: int = 1
     gamma: int = 2
+    # layers this run holds: 0 for the architecture's own depth, else a
+    # whole number of its periods (one pipeline stage of a deeper model)
+    layers: int = 0
+    # vocabulary rows this run holds: 0 for the architecture's own, else
+    # its share of a vocabulary split by rows over several chips
+    vocab: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -150,6 +150,10 @@ def fedprox_accum_2d(x, g, anchor, acc, coef, active, eta, mu, *,
 
         x_new   = x - active*eta*(g + mu*(x - anchor))
         acc_new = acc + active*coef*g
+
+    ``x_new`` and ``acc_new`` are written over ``x`` and ``acc`` (each
+    tile is read before it is written), so a local iteration holds four
+    planes and not six; XLA copies an input that is still needed.
     """
     G, R, L = x.shape
     assert L == LANE and R % 8 == 0, (G, R, L)
@@ -182,6 +186,7 @@ def fedprox_accum_2d(x, g, anchor, acc, coef, active, eta, mu, *,
         out_specs=[bspec, bspec],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(acc.shape, acc.dtype)],
+        input_output_aliases={0: 0, 3: 1},
         interpret=interpret,
         compiler_params=_compiler_params(
             plan, interpret, ("parallel", "parallel", "parallel")),

@@ -46,10 +46,20 @@ def _constrain_bshd(x):
         return x
 
 
+def softmax_scale(scale: Optional[float], head_dim: int):
+    """The factor on ``q . k``: ``scale``, or ``1/sqrt(head_dim)`` where it
+    is None.  The forward passes and the flash backward all take it from
+    here, so they cannot disagree."""
+    if scale is None:
+        return 1.0 / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
+    return scale
+
+
 def blocked_attention(q, k, v, *, causal: bool = True,
                       window: Optional[int] = None,
                       q_block: int = 512, kv_block: int = 512,
-                      flash_vjp: bool = True):
+                      flash_vjp: bool = True,
+                      scale: Optional[float] = None):
     """Memory-O(S*block) attention with online softmax.
 
     q: (B, S, Hq, D); k, v: (B, S, Hkv, D).  Returns (B, S, Hq, D).
@@ -60,15 +70,19 @@ def blocked_attention(q, k, v, *, causal: bool = True,
     ``flash_vjp``: use the custom flash backward (recompute probabilities per
     block; saves only out+lse).  Without it, AD through the scans stores every
     (q_block x kv_block) probability tile — O(S^2) memory.
+
+    ``scale``: the softmax scale (``softmax_scale``).
     """
     if flash_vjp:
-        return _flash_attention(q, k, v, causal, window, q_block, kv_block)
+        return _flash_attention(q, k, v, causal, window, q_block, kv_block,
+                                scale)
     return _blocked_attention_fwd_only(q, k, v, causal=causal, window=window,
-                                       q_block=q_block, kv_block=kv_block)[0]
+                                       q_block=q_block, kv_block=kv_block,
+                                       scale=scale)[0]
 
 
 def _blocked_attention_fwd_only(q, k, v, *, causal, window, q_block,
-                                kv_block):
+                                kv_block, scale=None):
     """Forward pass; returns (out, lse) with lse: (B, Hkv, G, S) f32."""
     q = _constrain_bshd(q)
     k = _constrain_bshd(k)
@@ -86,7 +100,7 @@ def _blocked_attention_fwd_only(q, k, v, *, causal, window, q_block,
     q_block = _fit(S, q_block)
     kv_block = _fit(S_kv, kv_block)
     nq, nk = S // q_block, S_kv // kv_block
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    scale = softmax_scale(scale, D)
 
     qb = q.reshape(B, nq, q_block, Hkv, G, D)
     kb = k.reshape(B, nk, kv_block, Hkv, D)
@@ -137,22 +151,22 @@ def _blocked_attention_fwd_only(q, k, v, *, causal, window, q_block,
     return out.astype(q.dtype), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, window, q_block, kv_block):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, window, q_block, kv_block, scale):
     out, _ = _blocked_attention_fwd_only(q, k, v, causal=causal,
                                          window=window, q_block=q_block,
-                                         kv_block=kv_block)
+                                         kv_block=kv_block, scale=scale)
     return out
 
 
-def _flash_fwd(q, k, v, causal, window, q_block, kv_block):
+def _flash_fwd(q, k, v, causal, window, q_block, kv_block, scale):
     out, lse = _blocked_attention_fwd_only(q, k, v, causal=causal,
                                            window=window, q_block=q_block,
-                                           kv_block=kv_block)
+                                           kv_block=kv_block, scale=scale)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, window, q_block, kv_block, res, dout):
+def _flash_bwd(causal, window, q_block, kv_block, scale, res, dout):
     """Recompute probabilities per (q,kv) block pair (FlashAttention-2
     backward), accumulating dq over kv blocks and dk/dv over q blocks."""
     q, k, v, out, lse = res
@@ -174,7 +188,7 @@ def _flash_bwd(causal, window, q_block, kv_block, res, dout):
     qb_sz = _fit(S, q_block)
     kb_sz = _fit(S_kv, kv_block)
     nq, nk = S // qb_sz, S_kv // kb_sz
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    scale = softmax_scale(scale, D)
 
     qg = q.reshape(B, nq, qb_sz, Hkv, G, D)
     kg = k.reshape(B, nk, kb_sz, Hkv, D)
@@ -237,14 +251,15 @@ _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 def decode_attention_plain(q, k_cache, v_cache, cache_len, *,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None,
+                           scale: Optional[float] = None):
     """Single-token decode. q: (B, Hq, D); caches: (B, S, Hkv, D);
     cache_len: () or (B,) number of valid positions (the new token's position
     is cache_len-1 after insertion).  Returns (B, Hq, D)."""
     B, S, Hkv, D = k_cache.shape
     Hq = q.shape[1]
     G = Hq // Hkv
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    scale = softmax_scale(scale, D)
     qg = q.reshape(B, Hkv, G, D)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
                    preferred_element_type=jnp.float32) * scale
@@ -265,7 +280,8 @@ def decode_attention_plain(q, k_cache, v_cache, cache_len, *,
 
 def decode_attention_seq_sharded(q, k_cache, v_cache, cache_len, *,
                                  ctx: ShardCtx,
-                                 window: Optional[int] = None):
+                                 window: Optional[int] = None,
+                                 scale: Optional[float] = None):
     """Decode attention with the KV cache sequence-sharded over
     ``ctx.cache_axes``.  Each shard computes a partial safe-softmax
     (m, l, o); partials are combined with psum/pmax over the cache axes.
@@ -290,10 +306,10 @@ def decode_attention_seq_sharded(q, k_cache, v_cache, cache_len, *,
         offset = idx * s_local
         Hq = q.shape[1]
         G = Hq // Hkv
-        scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
         qg = q.reshape(q.shape[0], Hkv, G, D)
         s = jnp.einsum("bkgd,bskd->bkgs", qg, kc,
-                       preferred_element_type=jnp.float32) * scale
+                       preferred_element_type=jnp.float32) * \
+            softmax_scale(scale, D)
         pos = offset + jnp.arange(s_local, dtype=jnp.int32)
         cl = jnp.asarray(clen)
         cl = cl[:, None] if cl.ndim == 1 else cl[None]

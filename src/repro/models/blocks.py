@@ -20,6 +20,7 @@ from repro.models import mamba as mamba_lib
 from repro.models import moe as moe_lib
 from repro.models.common import (ShardCtx, apply_rope, dense_init,
                                  rms_norm, rope_frequencies)
+from repro.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,6 +116,12 @@ def init_stacked_params(key, cfg: ModelConfig, dtype):
 # --------------------------------------------------------------- apply ----
 
 def mlp_forward(p, x, cfg: ModelConfig):
+    """The (gated) MLP; its ops and their gradients carry ``cefl.mlp``."""
+    with jax.named_scope(tracing.MLP):
+        return _mlp_forward(p, x, cfg)
+
+
+def _mlp_forward(p, x, cfg: ModelConfig):
     h = x @ p["w_in"]
     if cfg.gated_mlp:
         g = x @ p["w_gate"]
@@ -126,7 +133,17 @@ def mlp_forward(p, x, cfg: ModelConfig):
 
 def attn_forward(p, x, cfg: ModelConfig, *, angles, causal=True,
                  kv_override=None, q_block=512, kv_block=512):
-    """Full-sequence attention (train / prefill / encoder / cross)."""
+    """Full-sequence attention (train / prefill / encoder / cross): the
+    projections, blocked attention and output projection, whose ops and
+    gradients carry ``cefl.attn``."""
+    with jax.named_scope(tracing.ATTN):
+        return _attn_forward(p, x, cfg, angles=angles, causal=causal,
+                             kv_override=kv_override, q_block=q_block,
+                             kv_block=kv_block)
+
+
+def _attn_forward(p, x, cfg: ModelConfig, *, angles, causal, kv_override,
+                  q_block, kv_block):
     q = jnp.einsum("bsd,dhx->bshx", x, p["wq"])
     kv_src = x if kv_override is None else kv_override
     k = jnp.einsum("bsd,dhx->bshx", kv_src, p["wk"])
@@ -142,7 +159,7 @@ def attn_forward(p, x, cfg: ModelConfig, *, angles, causal=True,
     out = attn_lib.blocked_attention(
         q, k, v, causal=causal,
         window=cfg.sliding_window if causal else None,
-        q_block=q_block, kv_block=kv_block)
+        q_block=q_block, kv_block=kv_block, scale=cfg.attention_multiplier)
     return jnp.einsum("bshx,hxd->bsd", out, p["wo"]), (k, v)
 
 
@@ -155,7 +172,7 @@ def attn_decode(p, x, cfg: ModelConfig, cache, pos, *, ctx: ShardCtx,
     if "q_norm" in p:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if not cfg.is_encdec:   # RoPE (enc-dec uses learned absolute positions)
+    if not (cfg.is_encdec or cfg.nope):   # RoPE (enc-dec: learned positions)
         angle = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                  jnp.asarray(pos)[None])      # (1, D/2)
         q = apply_rope(q[:, None], angle)[:, 0]
@@ -174,10 +191,12 @@ def attn_decode(p, x, cfg: ModelConfig, cache, pos, *, ctx: ShardCtx,
     eff_window = None if (window is not None and S == window) else window
     if ctx.seq_shard_decode and ctx.on_mesh:
         out = attn_lib.decode_attention_seq_sharded(
-            q, kc, vc, cache_len, ctx=ctx, window=eff_window)
+            q, kc, vc, cache_len, ctx=ctx, window=eff_window,
+            scale=cfg.attention_multiplier)
     else:
-        out = attn_lib.decode_attention_plain(q, kc, vc, cache_len,
-                                              window=eff_window)
+        out = attn_lib.decode_attention_plain(
+            q, kc, vc, cache_len, window=eff_window,
+            scale=cfg.attention_multiplier)
     y = jnp.einsum("bhx,hxd->bd", out, p["wo"])
     return y, {"k": kc, "v": vc}
 
@@ -186,8 +205,17 @@ def cross_attn_decode(p, x, cfg: ModelConfig, cross_cache):
     """Decoder cross-attention against a fixed encoder cache."""
     q = jnp.einsum("bd,dhx->bhx", x, p["wq"])
     kc, vc = cross_cache["k"], cross_cache["v"]
-    out = attn_lib.decode_attention_plain(q, kc, vc, kc.shape[1])
+    out = attn_lib.decode_attention_plain(q, kc, vc, kc.shape[1],
+                                          scale=cfg.attention_multiplier)
     return jnp.einsum("bhx,hxd->bd", out, p["wo"])
+
+
+def residual_branch(y, cfg: ModelConfig):
+    """A mixer's or MLP's output as added to the residual stream: times
+    ``residual_multiplier``, which at 1 is not applied at all."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * cfg.residual_multiplier
 
 
 def layer_forward(params, x, cfg: ModelConfig, spec: LayerSpec, *,
@@ -210,17 +238,17 @@ def layer_forward(params, x, cfg: ModelConfig, spec: LayerSpec, *,
         else:
             y = mamba_lib.ssd_forward(params["mamba"], h, cfg.ssm,
                                       init_state=ssm_state)
-    x = x + y
+    x = x + residual_branch(y, cfg)
     if spec.use_moe:
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
         y, moe_aux = moe_lib.moe_forward(params["moe"], h, cfg.moe)
         if "mlp" in params:   # arctic dense residual / llama4 shared expert
             y = y + mlp_forward(params["mlp"], h, cfg)
         aux = moe_aux
-        x = x + y
+        x = x + residual_branch(y, cfg)
     elif spec.has_mlp:
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
-        x = x + mlp_forward(params["mlp"], h, cfg)
+        x = x + residual_branch(mlp_forward(params["mlp"], h, cfg), cfg)
     return x, aux, kv, new_state
 
 
@@ -234,7 +262,7 @@ def layer_decode(params, x, cfg: ModelConfig, spec: LayerSpec, cache, pos, *,
     else:
         y, new_cache = mamba_lib.mamba_decode_step(params["mamba"], h,
                                                    cache, cfg.ssm)
-    x = x + y
+    x = x + residual_branch(y, cfg)
     if spec.use_moe:
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
         gs = min(1024, h.shape[0])
@@ -243,8 +271,8 @@ def layer_decode(params, x, cfg: ModelConfig, spec: LayerSpec, cache, pos, *,
         y = y[:, 0]
         if "mlp" in params:
             y = y + mlp_forward(params["mlp"], h, cfg)
-        x = x + y
+        x = x + residual_branch(y, cfg)
     elif spec.has_mlp:
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
-        x = x + mlp_forward(params["mlp"], h, cfg)
+        x = x + residual_branch(mlp_forward(params["mlp"], h, cfg), cfg)
     return x, new_cache

@@ -72,7 +72,7 @@ def _init_cross_params(key, cfg: ModelConfig, dtype):
 # -------------------------------------------------------------- forward ---
 
 def _angles(cfg: ModelConfig, S: int):
-    if cfg.is_encdec:
+    if cfg.is_encdec or cfg.nope:
         return None
     pos = jnp.arange(S, dtype=jnp.int32)
     return rope_frequencies(cfg.head_dim, cfg.rope_theta, pos)
@@ -95,19 +95,30 @@ def lm_backbone(params, cfg: ModelConfig, x, *, remat: bool = True,
     residual stream is n_periods/g copies instead of n_periods (activation
     memory / g at ~2x in-chunk recompute) — the coarse-remat lever used by
     the deep/wide configs (llama3-405b) and tuned in EXPERIMENTS.md §Perf.
+
+    Recomputation is per layer: a one-layer period is checkpointed whole,
+    and inside a longer period each layer is checkpointed on its own in
+    place of the period, so the backward holds one layer's intermediates
+    and not the whole period's.
     """
     specs = B.period_spec(cfg)
     S = x.shape[1]
     angles = _angles(cfg, S)
+    per_layer = remat and len(specs) > 1
+
+    def layer_fn(lp, x, spec):
+        x, aux, _, _ = B.layer_forward(lp, x, cfg, spec, angles=angles,
+                                       q_block=q_block, kv_block=kv_block)
+        return x, aux
 
     def period_fn(x, pp):
         lb = jnp.zeros((), jnp.float32)
         rz = jnp.zeros((), jnp.float32)
         block_p, cross_p = pp if cfg.is_encdec else (pp, None)
         for j, spec in enumerate(specs):
-            x, aux, _, _ = B.layer_forward(
-                block_p[f"layer_{j}"], x, cfg, spec, angles=angles,
-                q_block=q_block, kv_block=kv_block)
+            fn = jax.checkpoint(layer_fn, static_argnums=(2,)) \
+                if per_layer else layer_fn
+            x, aux = fn(block_p[f"layer_{j}"], x, spec)
             if cfg.is_encdec:
                 cp = cross_p[f"layer_{j}"]
                 h = rms_norm(x, cp["ln_x"], cfg.norm_eps)
@@ -131,7 +142,8 @@ def lm_backbone(params, cfg: ModelConfig, x, *, remat: bool = True,
         xs = jax.tree_util.tree_map(
             lambda a: a.reshape((n_per // remat_chunk, remat_chunk)
                                 + a.shape[1:]), xs)
-        inner_period = jax.checkpoint(period_fn) if remat else period_fn
+        inner_period = jax.checkpoint(period_fn) if remat and \
+            not per_layer else period_fn
 
         def chunk_fn(x, pp_chunk):
             def inner(carry, pp):
@@ -143,7 +155,8 @@ def lm_backbone(params, cfg: ModelConfig, x, *, remat: bool = True,
 
         body = jax.checkpoint(chunk_fn) if remat else chunk_fn
     else:
-        body = jax.checkpoint(period_fn) if remat else period_fn
+        body = jax.checkpoint(period_fn) if remat and not per_layer \
+            else period_fn
 
     def scan_body(carry, pp):
         x, lb, rz = carry
@@ -175,8 +188,17 @@ def encoder_forward(params, cfg: ModelConfig, enc_embed, *,
     return rms_norm(x, params["enc"]["final_norm"], cfg.norm_eps)
 
 
-def embed_tokens(params, cfg: ModelConfig, tokens, pos_offset=0):
+def _token_embeddings(params, cfg: ModelConfig, tokens):
+    """Rows of the embedding table, times ``embedding_multiplier`` (not
+    applied at 1)."""
     x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def embed_tokens(params, cfg: ModelConfig, tokens, pos_offset=0):
+    x = _token_embeddings(params, cfg, tokens)
     if cfg.is_encdec:
         S = tokens.shape[-1]
         pe = jax.lax.dynamic_slice_in_dim(params["pos_embed"], pos_offset, S, 0)
@@ -185,9 +207,13 @@ def embed_tokens(params, cfg: ModelConfig, tokens, pos_offset=0):
 
 
 def unembed(params, cfg: ModelConfig, x):
+    """Logits (float32), divided by ``logits_scaling`` (not at 1)."""
     table = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    return jnp.einsum("...d,dv->...v", x, table,
-                      preferred_element_type=jnp.float32)
+    logits = jnp.einsum("...d,dv->...v", x, table,
+                        preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:    # repro: noqa(RPA004) a static config field, never a tracer
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def chunked_loss(params, cfg: ModelConfig, x, labels, mask=None,
@@ -281,7 +307,7 @@ def lm_decode_step(params, cfg: ModelConfig, tokens, cache, *,
     (logits (B, V), new_cache)."""
     specs = B.period_spec(cfg)
     pos = cache["pos"]
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = _token_embeddings(params, cfg, tokens)
     if cfg.is_encdec:
         x = x + jnp.take(params["pos_embed"], pos, axis=0).astype(x.dtype)
 
@@ -361,7 +387,7 @@ def prefill(params, cfg: ModelConfig, tokens, cache_len: int, *,
                 y, (k, v) = B.attn_forward(
                     block_p[f"layer_{j}"]["attn"], h, cfg, angles=angles,
                     q_block=q_block, kv_block=kv_block)
-                x = x_res + y
+                x = x_res + B.residual_branch(y, cfg)
                 if cfg.sliding_window is not None and S > cfg.sliding_window:
                     k = k[:, -cfg.sliding_window:]
                     v = v[:, -cfg.sliding_window:]
@@ -372,10 +398,11 @@ def prefill(params, cfg: ModelConfig, tokens, cache_len: int, *,
                     y, _ = B.moe_lib.moe_forward(lp["moe"], h, cfg.moe)
                     if "mlp" in lp:
                         y = y + B.mlp_forward(lp["mlp"], h, cfg)
-                    x = x + y
+                    x = x + B.residual_branch(y, cfg)
                 elif "mlp" in lp:
                     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-                    x = x + B.mlp_forward(lp["mlp"], h, cfg)
+                    x = x + B.residual_branch(
+                        B.mlp_forward(lp["mlp"], h, cfg), cfg)
             else:
                 x, aux, _, st = B.layer_forward(
                     block_p[f"layer_{j}"], x, cfg, spec, angles=angles,

@@ -79,7 +79,7 @@ def _gated_rmsnorm(y, z, scale, eps=1e-5):
 def ssd_forward(params: dict, x_in: jnp.ndarray, s: SSMConfig,
                 init_state: Optional[dict] = None,
                 return_state: bool = False):
-    """Chunked SSD. x_in: (B, S, d_model); S % chunk == 0.
+    """Chunked SSD. x_in: (B, S, d_model); S % chunk == 0 or S < chunk.
     Returns y (B,S,d_model) and optionally {"h":..., "conv":...}.
     Its ops, and their gradients, carry the ``cefl.ssd`` named scope."""
     with jax.named_scope(tracing.SSD):
@@ -89,7 +89,9 @@ def ssd_forward(params: dict, x_in: jnp.ndarray, s: SSMConfig,
 def _ssd_forward(params, x_in, s, init_state, return_state):
     B, S, d_model = x_in.shape
     d_inner, H, d_conv = mamba_dims(d_model, s)
-    N, P, Q = s.state_dim, s.head_dim, s.chunk_size
+    # a sequence shorter than a chunk is one chunk (the result does not
+    # depend on the chunk size)
+    N, P, Q = s.state_dim, s.head_dim, min(s.chunk_size, S)
     assert S % Q == 0, (S, Q)
     nc = S // Q
 

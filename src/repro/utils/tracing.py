@@ -6,8 +6,9 @@ its keyword counters come back as the event's stats in the trace.  A span
 given ``round=`` hands that round to every span opened inside it, so all
 spans of one round carry the same ``round``.  With no trace running a span
 does nothing, and a counter that costs work to compute is computed only
-``if enabled()``.  The named scopes ``EQ10``, ``EQ11`` and ``SSD`` label
-the device ops of those computations in the HLO ``op_name`` metadata.
+``if enabled()``.  The named scopes ``EQ10``, ``EQ11``, ``SSD``, ``ATTN``
+and ``MLP`` label the device ops of those computations in the HLO
+``op_name`` metadata.
 
 Two rules hold for every span name: it never contains ``compile`` (trace
 readers take such host events for compiles), and it is never one of a
@@ -24,6 +25,7 @@ from jax.profiler import TraceAnnotation
 
 PREFIX = "cefl/"
 EQ10, EQ11, SSD = "cefl.eq10", "cefl.eq11", "cefl.ssd"
+ATTN, MLP = "cefl.attn", "cefl.mlp"
 
 _local = threading.local()
 

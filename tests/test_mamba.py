@@ -42,8 +42,9 @@ def test_ssd_state_chaining():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("chunk", [2, 8, 16])
+@pytest.mark.parametrize("chunk", [2, 8, 16, 64])
 def test_ssd_chunk_invariance(chunk):
+    """Also a chunk longer than the sequence (64 > 16): one chunk."""
     import dataclasses
     p, x = _setup(S=16)
     cfg2 = dataclasses.replace(S_CFG, chunk_size=chunk)
