@@ -11,6 +11,12 @@ kernels in ``fedprox_update.py`` / ``nova_aggregate.py``; tree views are
 materialized only at API boundaries (loss/grad evaluation, ``RoundReport``,
 checkpoints, eval).
 
+A tree view never builds a flat view of the whole plane: each leaf is
+sliced from its own static row window ``plane[r0:r1]``, and the view's
+backward (a ``jax.custom_vjp``) is :meth:`FlatSpec.flatten` of the
+cotangent tree, one concatenation into a fresh plane.  Both halves run
+under the ``cefl.unflatten`` named scope.
+
 Layout rules:
 
 * ``LANE = 1024`` (multiple of the 128-lane register width) is the fixed
@@ -32,6 +38,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.utils import tracing
 
 LANE = 1024      # last-dim tile width (multiple of 128)
 SUBLANE = 8      # f32 min sublane multiple; every plane has R % 8 == 0
@@ -69,20 +77,18 @@ class FlatSpec:
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         assert treedef == self.treedef, (treedef, self.treedef)
         parts = [jnp.ravel(x).astype(jnp.float32) for x in leaves]
-        flat = (jnp.concatenate(parts) if parts
-                else jnp.zeros((0,), jnp.float32))
-        flat = jnp.pad(flat, (0, self.rows * LANE - self.n))
-        return flat.reshape(self.rows, LANE)
+        # the zero padding as the last part: one concatenation writes the
+        # plane, where a pad after it would copy the whole plane again
+        parts.append(jnp.zeros((self.rows * LANE - self.n,), jnp.float32))
+        return jnp.concatenate(parts).reshape(self.rows, LANE)
 
     def unflatten(self, plane: jnp.ndarray):
-        """(R, LANE) plane -> pytree with the original shapes/dtypes."""
-        flat = plane.reshape(-1)
-        out = []
-        for shape, dtype, off in zip(self.shapes, self.dtypes, self.offsets):
-            k = int(np.prod(shape)) if shape else 1
-            out.append(jax.lax.dynamic_slice_in_dim(flat, off, k)
-                       .reshape(shape).astype(dtype))
-        return jax.tree_util.tree_unflatten(self.treedef, out)
+        """(R, LANE) plane -> pytree with the original shapes/dtypes; its
+        backward is :meth:`flatten` of the cotangent tree."""
+        view = _VIEW_CACHE.get(self)
+        if view is None:
+            view = _VIEW_CACHE[self] = _tree_view(self)
+        return view(plane)
 
     def unflatten_batched(self, planes: jnp.ndarray):
         """(G, R, LANE) -> pytree whose leaves carry the leading G axis."""
@@ -101,7 +107,41 @@ class FlatSpec:
         return isinstance(other, FlatSpec) and self._key() == other._key()
 
 
+def _tree_view(spec: FlatSpec):
+    """``spec``'s plane -> tree view.  Each leaf comes from the static row
+    window that holds it (rows ``off // LANE`` up to ``ceil((off + k) /
+    LANE)``), never from a flat view of the whole plane, which a v5e
+    relays out whole and XLA may rematerialize at each use.  The backward
+    is ``spec.flatten`` of the cotangents, one concatenation into a fresh
+    plane, where autodiff's own would pad each leaf's cotangent into
+    plane-sized zeros."""
+
+    def tree(plane):
+        with jax.named_scope(tracing.UNFLATTEN):
+            out = []
+            for shape, dtype, off in zip(spec.shapes, spec.dtypes,
+                                         spec.offsets):
+                k = int(np.prod(shape)) if shape else 1
+                r0, r1 = off // LANE, -(-(off + k) // LANE)
+                s = off - r0 * LANE
+                out.append(plane[r0:r1].reshape(-1)[s:s + k]
+                           .reshape(shape).astype(dtype))
+            return jax.tree_util.tree_unflatten(spec.treedef, out)
+
+    def forward(plane):
+        return tree(plane), None
+
+    def backward(_, cotangent):
+        with jax.named_scope(tracing.UNFLATTEN):
+            return (spec.flatten(cotangent),)
+
+    view = jax.custom_vjp(tree)
+    view.defvjp(forward, backward)
+    return view
+
+
 _SPEC_CACHE: dict = {}
+_VIEW_CACHE: dict = {}      # FlatSpec -> its tree view (custom_vjp)
 
 
 def spec_of(tree) -> FlatSpec:

@@ -14,6 +14,7 @@ from repro.kernels import ops, ref
 from repro.kernels.fedprox_update import LANE, fedprox_accum_2d
 from repro.kernels.plane import ParamPlane, as_tree, spec_of
 from repro.models.classifier import classifier_loss, init_classifier_params
+from repro.utils import tracing
 
 KEY = jax.random.PRNGKey(0)
 
@@ -51,6 +52,83 @@ def test_flatspec_roundtrip(tree):
     batched = stacked.to_tree()
     _assert_tree_equal(
         jax.tree_util.tree_map(lambda x: x[1], batched), tree)
+
+
+def _flat_view(spec, plane):
+    """The reference tree view: one flat view of the whole plane, each leaf
+    a dynamic slice of it, differentiated by autodiff."""
+    flat = plane.reshape(-1)
+    out = []
+    for shape, dtype, off in zip(spec.shapes, spec.dtypes, spec.offsets):
+        k = int(np.prod(shape)) if shape else 1
+        out.append(jax.lax.dynamic_slice_in_dim(flat, off, k)
+                   .reshape(shape).astype(dtype))
+    return jax.tree_util.tree_unflatten(spec.treedef, out)
+
+
+def _sin_loss(view):
+    """A weighted sum of ``sin`` of every leaf of ``view(plane)``."""
+    def loss(plane):
+        leaves = jax.tree_util.tree_leaves(view(plane))
+        return sum((i + 1.0) * jnp.sum(jnp.sin(x.astype(jnp.float32)))
+                   for i, x in enumerate(leaves))
+    return loss
+
+
+def _classifier_shapes():
+    cfg = ClassifierConfig(input_shape=(28, 28, 1), hidden=(200, 100))
+    return jax.eval_shape(lambda: init_classifier_params(KEY, cfg))
+
+
+@pytest.mark.parametrize("shapes", [
+    # 3 x 700 straddles rows 0-2, the next leaf starts mid-row
+    {"a": ((3, 700), jnp.float32), "b": ((1500,), jnp.float32),
+     "c": ((2, 9), jnp.float32)},
+    # "b" ends on the row boundary 2048, "c" starts on it
+    {"a": ((3, 512), jnp.float32), "b": ((512,), jnp.float32),
+     "c": ((7, 3), jnp.float32)},
+    # scalars around a leaf longer than a row, and an empty leaf
+    {"s": ((), jnp.float32), "v": ((1030,), jnp.float32),
+     "e": ((0, 4), jnp.float32), "t": ((), jnp.float32)},
+    # bf16 leaves beside an f32 one
+    {"w": ((33, 40), jnp.bfloat16), "b": ((129,), jnp.bfloat16),
+     "f": ((1000,), jnp.float32)},
+    "classifier",
+], ids=["straddle", "row-end", "scalar", "bf16", "classifier"])
+def test_row_window_view_equals_flat_view(shapes):
+    """The row-window view and its ``flatten`` backward give, bit for bit,
+    the leaves and gradients of the flat view, and so under ``vmap`` over
+    a (G, R, LANE) stack."""
+    if shapes == "classifier":
+        tree = _classifier_shapes()
+    else:
+        tree = {k: jax.ShapeDtypeStruct(s, d) for k, (s, d) in shapes.items()}
+    spec = spec_of(tree)
+    stack = jax.random.normal(jax.random.PRNGKey(1), (3, spec.rows, LANE))
+    plane = stack[0]
+
+    _assert_tree_equal(jax.jit(spec.unflatten)(plane),
+                       jax.jit(lambda p: _flat_view(spec, p))(plane))
+    new, ref = _sin_loss(spec.unflatten), _sin_loss(
+        lambda p: _flat_view(spec, p))
+    np.testing.assert_array_equal(jax.jit(jax.grad(new))(plane),
+                                  jax.jit(jax.grad(ref))(plane))
+    np.testing.assert_array_equal(
+        jax.jit(jax.vmap(jax.grad(new)))(stack),
+        jax.jit(jax.vmap(jax.grad(ref)))(stack))
+    # batched views are vmap(unflatten): leaves carry the leading G axis
+    _assert_tree_equal(spec.unflatten_batched(stack),
+                       jax.vmap(lambda p: _flat_view(spec, p))(stack))
+
+
+def test_view_ops_carry_the_unflatten_scope():
+    """The view's forward and its backward label their ops (``op_name``)
+    with the ``cefl.unflatten`` scope, which the trace reader keys on."""
+    spec = spec_of({"a": jnp.zeros((3, 700)), "b": jnp.zeros((1500,))})
+    text = jax.jit(jax.grad(_sin_loss(spec.unflatten))).lower(
+        jnp.zeros((spec.rows, LANE))).compile().as_text()
+    assert f"jvp({tracing.UNFLATTEN})/" in text
+    assert f"transpose(jvp({tracing.UNFLATTEN}))/" in text
 
 
 def test_spec_is_cached_and_hashable():

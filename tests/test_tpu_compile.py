@@ -13,8 +13,12 @@ Three plane sizes: the ``paper_table1`` classifier (28x28 MLP 200-100,
 mamba2-130m at published widths (~1.3e5 rows, G = 2 DPUs as in the
 ``lm_mamba2_130m`` preset) and granite-4.0-h-micro's one-chip share
 (~7.8e5 rows, a 3.2 GB plane, G = 1 as in ``lm_granite4_h_micro``).
+At the two LM sizes the gradient through the plane's tree view is
+compiled too, to show it builds no flat view of the whole plane.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,15 +52,15 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _plane_dims(size: str):
-    """(rows R, DPU count G) of the size's parameter plane."""
+def _plane_spec(size: str):
+    """(FlatSpec, DPU count G) of the size's parameter plane."""
     if size == "paper_table1":
         from repro.models.classifier import (ClassifierConfig,
                                              init_classifier_params)
         cfg = ClassifierConfig(input_shape=(28, 28, 1), hidden=(200, 100))
         shapes = jax.eval_shape(
             lambda: init_classifier_params(jax.random.PRNGKey(0), cfg))
-        return spec_of(shapes).rows, 25
+        return spec_of(shapes), 25
     import dataclasses
 
     from repro.configs import get_config
@@ -71,7 +75,13 @@ def _plane_dims(size: str):
         cfg, n_dpu = get_config("mamba2-130m"), 2
     shapes = jax.eval_shape(
         lambda: L.init_lm_params(jax.random.PRNGKey(0), cfg, jnp.float32))
-    return spec_of(shapes).rows, n_dpu
+    return spec_of(shapes), n_dpu
+
+
+def _plane_dims(size: str):
+    """(rows R, DPU count G) of the size's parameter plane."""
+    spec, n_dpu = _plane_spec(size)
+    return spec.rows, n_dpu
 
 
 def _program(kernel: str, R: int, G: int):
@@ -129,3 +139,32 @@ def test_named_scopes_keep_the_kernel_names(one_chip, kernel, scope):
         line.split(" = ", 1)[0].split("%")[-1].startswith(f"{kernel}_2d.")
         for line in calls)
     assert all(f"/{scope}/" in line for line in calls)
+
+
+_HLO_VALUE = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("size", ("mamba2_130m", "granite4_h_micro"))
+def test_tree_view_grad_builds_no_flat_plane(one_chip, size):
+    """``grad`` through ``spec.unflatten`` compiles with no plane-sized
+    ``copy`` (the flat view's relayout from (8,128) to (1,128) tiles) and at most one
+    one-dimensional plane-sized value: the backward's concatenation."""
+    spec, _ = _plane_spec(size)
+
+    def loss(plane):
+        leaves = jax.tree_util.tree_leaves(spec.unflatten(plane))
+        return sum((i + 1.0) * jnp.sum(jnp.sin(x.astype(jnp.float32)))
+                   for i, x in enumerate(leaves))
+
+    arg = jax.ShapeDtypeStruct((spec.rows, LANE), jnp.float32,
+                               sharding=one_chip)
+    text = jax.jit(jax.grad(loss)).lower(arg).compile().as_text()
+    plane_sized = []            # (dims, opcode), parameters left out
+    for line in text.splitlines():
+        m = _HLO_VALUE.match(line)
+        if m and m.group(2) != "parameter":
+            dims = [int(d) for d in m.group(1).split(",") if d]
+            if math.prod(dims) >= spec.n:
+                plane_sized.append((dims, m.group(2)))
+    assert not [v for v in plane_sized if v[1] == "copy"], plane_sized
+    assert len([v for v in plane_sized if len(v[0]) == 1]) <= 1, plane_sized
